@@ -421,8 +421,8 @@ class _BodyInterp:
 
         elif name == "cond":
             self._cond(eqn, env, guarded)
-        elif name == "pjit":
-            self._pjit(eqn, env, guarded)
+        elif name == "jit":
+            self._jit(eqn, env, guarded)
         elif name in ("custom_jvp_call", "custom_vjp_call",
                       "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"):
             closed = (eqn.params.get("call_jaxpr")
@@ -616,7 +616,7 @@ class _BodyInterp:
             outs.append(_join(*[r[i] for r in results if i < len(r)]))
         self._bind(eqn, env, *outs)
 
-    def _pjit(self, eqn, env: _Env, guarded: bool):
+    def _jit(self, eqn, env: _Env, guarded: bool):
         pname = eqn.params.get("name", "")
         closed = eqn.params.get("jaxpr")
         iv = [env.read(v) for v in eqn.invars if not env.is_ref(v)]

@@ -9,12 +9,12 @@ instead of reusing a stale compiled path. The policy is reduced to the
 fields each op actually consumes before it becomes a jit key, so unrelated
 overrides (e.g. attention's `chunk`) never recompile matmuls.
 
-Caveat (inherited from any Python-level config, including the old
-`use_pallas` flag): a CALLER-level `jax.jit` around code that calls these
-ops bakes the ambient policy in at its own trace time — the caller's cache
-key cannot see the thread-local. Pin one policy per traced program (as
-ServingEngine does via its `policy=` argument) or pass `policy=` explicitly
-so it participates in your own static args.
+Caveat (inherited from any Python-level config): a CALLER-level `jax.jit`
+around code that calls these ops bakes the ambient policy in at its own
+trace time — the caller's cache key cannot see the thread-local. Pin one
+policy per traced program (as ServingEngine does via its `policy=`
+argument) or pass `policy=` explicitly so it participates in your own
+static args.
 
     from repro import api
 
@@ -143,13 +143,13 @@ def attention_route(*, lq: int, lk: Optional[int] = None, causal: bool = True,
     "pallas-prefill", and single-token decode steps (plus legacy
     scalar-offset short queries) to "pallas-decode"; 128-aligned
     scalar-offset full-sequence prefill routes to the "pallas" flash kernel;
-    everything else (and every shape under backend="ref"/"auto"-off) falls
-    back to "ref". Cache-shaped means lk > lq or a per-row offset vector
-    (which only caches produce): the serving kernels are forward-only (no
-    VJP), and plain short self-attention (lk == lq, scalar offset — e.g. a
-    tiny training forward) must stay on the differentiable ref path. Exposed
-    so serving benchmarks/engines can report the path their decode steps and
-    prefill chunks take.
+    everything else (and every shape under backend="ref", or "auto" off a
+    TPU backend) falls back to "ref". Cache-shaped means lk > lq or a
+    per-row offset vector (which only caches produce): the serving kernels
+    are forward-only (no VJP), and plain short self-attention (lk == lq,
+    scalar offset — e.g. a tiny training forward) must stay on the
+    differentiable ref path. Exposed so serving benchmarks/engines can
+    report the path their decode steps and prefill chunks take.
     """
     pol = _resolve(policy, backend=backend)
     if pol.use_pallas():

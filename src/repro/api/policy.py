@@ -18,6 +18,7 @@ import dataclasses
 import threading
 from typing import Any, Iterator, Optional
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["ExecutionPolicy", "policy", "current_policy", "default_policy",
@@ -34,8 +35,8 @@ class ExecutionPolicy:
 
     format:    AIO number format for the quantized-matmul/quantize plane.
     backend:   "pallas" forces the Pallas kernels, "ref" the pure-jnp oracle,
-               "auto" defers to the legacy `kernels.common.use_pallas` flag
-               (False by default — the XLA path that lowers on any backend).
+               "auto" picks from the platform: the kernels on a TPU backend,
+               the XLA reference path everywhere else.
     bm/bn/bk:  MXU tile sizes for matmul-family kernels.
     bh/bc:     height/channel tiles for the depthwise kernel.
     bkv:       KV-block length of the flash decode/prefill attention kernels.
@@ -71,8 +72,7 @@ class ExecutionPolicy:
             return True
         if self.backend == "ref":
             return False
-        from ..kernels import common       # deferred: kernels import the api
-        return common.pallas_enabled()
+        return jax.default_backend() == "tpu"
 
     def impl(self) -> str:
         """Registry implementation key this policy selects."""

@@ -4,14 +4,11 @@ Model code never imports jax.sharding directly: it calls `constrain(x, *spec)`
 with logical axis names ("model", the DP tuple from `ctx_dp_axes()`) and this
 module translates against whatever mesh is ambient — a no-op when none is.
 
-The helpers are version-tolerant: newer jax exposes the ambient mesh through
-`jax.sharding.get_abstract_mesh()` / `jax.set_mesh`, older releases through
-the `with mesh:` resource env. `set_mesh` / `ctx_mesh` pick whichever exists
-so launchers and the dry-run behave identically on both.
+The ambient mesh is the one bound by `jax.set_mesh` (re-exported here as
+`set_mesh`); `ctx_mesh` reads it back through `jax.sharding.get_abstract_mesh`.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Tuple
 
 import jax
@@ -24,23 +21,9 @@ DP_AXES = ("pod", "data")
 
 
 def ctx_mesh():
-    """The ambient mesh (abstract or physical), or None outside any context."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            am = get_abstract()
-            if am is not None and not am.empty:
-                return am
-        except Exception:  # noqa: BLE001
-            pass
-    try:
-        from jax.interpreters import pxla
-        pm = pxla.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:  # noqa: BLE001
-        pass
-    return None
+    """The ambient (abstract) mesh, or None outside any `set_mesh` block."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def ctx_dp_axes() -> Tuple[str, ...]:
@@ -77,14 +60,4 @@ def constrain(x: jax.Array, *spec) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, P(*entries))
 
 
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Bind `mesh` as the ambient mesh (jax.set_mesh where available, the
-    classic `with mesh:` resource env otherwise)."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+set_mesh = jax.set_mesh

@@ -12,4 +12,3 @@ from .aio_quant import aio_quantize  # noqa: F401
 from .depthwise import depthwise_conv  # noqa: F401
 from .flash_attention import attention, chunked_attention, mha_ref  # noqa: F401
 from .grouped_matmul import grouped_matmul, morphable_multi_gemm  # noqa: F401
-from .common import use_pallas, pallas_enabled  # noqa: F401

@@ -67,15 +67,16 @@ def _mm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, mode: str,
         w = decode_fp_code(w_ref[...], fmt.ebits, fmt.mbits, fmt.bias)
         acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
     elif mode == "int8":
-        x = x_ref[...].astype(jnp.int32)
-        w = w_ref[...].astype(jnp.int32)
-        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.int32)
+        # int8 x int8 -> int32 is the MXU's integer path; Mosaic refuses an
+        # int32 x int32 dot, so the operands stay in their stored dtype
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                                preferred_element_type=jnp.int32)
     elif mode == "int4":
         # packed along K: byte b holds K=2b (low nibble) and K=2b+1 (high);
         # dot(lo,lo) covers even K, dot(hi,hi) odd K — together the full
         # contraction, with half the HBM traffic (the 4x-results morph).
-        xlo, xhi = unpack_x(x_ref[...])
-        wlo, whi = unpack_w(w_ref[...])
+        xlo, xhi = unpack_nibbles(x_ref[...])
+        wlo, whi = unpack_nibbles(w_ref[...])
         acc_ref[...] += jnp.dot(xlo, wlo, preferred_element_type=jnp.int32)
         acc_ref[...] += jnp.dot(xhi, whi, preferred_element_type=jnp.int32)
     else:  # pragma: no cover
@@ -89,21 +90,16 @@ def _mm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, mode: str,
         o_ref[...] = acc.astype(out_dtype)
 
 
-def unpack_x(packed):
-    """x packed along its last (K) axis: (bm, bk//2) int8 -> two (bm, bk//2)
-    int32 operands for even/odd K. Even/odd split keeps dot shapes aligned."""
+def unpack_nibbles(packed):
+    """Packed int4 codes (int8 bytes, two nibbles each) -> (lo, hi) int8
+    operands in [-8, 7]. The packed axis is K for both layouts (x packs its
+    last axis, w its first), so the even/odd split keeps the two dots'
+    shapes aligned. Sign extension runs in int32 (the VPU's native width)
+    and narrows back to int8 for the MXU's int8 x int8 -> int32 path."""
     p32 = packed.astype(jnp.int32)
     lo = (p32 << 28) >> 28
     hi = p32 >> 4
-    return lo, hi
-
-
-def unpack_w(packed):
-    """w packed along its first (K) axis: (bk//2, bn) int8 -> (lo, hi)."""
-    p32 = packed.astype(jnp.int32)
-    lo = (p32 << 28) >> 28
-    hi = p32 >> 4
-    return lo, hi
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
 
 
 def aio_matmul_pallas(x, w, x_scale: Optional[jax.Array],

@@ -1,12 +1,11 @@
 """Shared helpers for the Pallas kernel layer.
 
 The kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling, MXU-aligned
-tiles); on this CPU container they are validated with interpret=True against
-the pure-jnp oracles in each kernel's ref.py. Backend choice lives in
-`repro.api.ExecutionPolicy`; the thread-local `use_pallas()` flag remains as
-the legacy default that policy backend="auto" defers to, so the multi-pod
-dry-run (CPU backend) lowers the pure-JAX paths while real-TPU deployments
-flip the flag or install `api.policy(backend="pallas")`.
+tiles); on a CPU backend they run with interpret=True and are validated
+against the pure-jnp oracles in each kernel's ref.py. Backend choice lives in
+`repro.api.ExecutionPolicy`: backend="auto" runs the kernels on a TPU
+backend and the pure-JAX paths everywhere else (so the multi-pod dry-run on
+the CPU backend lowers the XLA paths).
 """
 from __future__ import annotations
 
@@ -16,9 +15,8 @@ import threading
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ceil_div", "pad_to", "use_pallas", "pallas_enabled",
-           "interpret_mode", "interpret_override", "decode_fp_code",
-           "encode_fp_code", "MXU_LANE", "dtype_sublane"]
+__all__ = ["ceil_div", "pad_to", "interpret_mode", "interpret_override",
+           "decode_fp_code", "encode_fp_code", "MXU_LANE", "dtype_sublane"]
 
 MXU_LANE = 128          # lane (minor-most) tile quantum on TPU
 
@@ -45,13 +43,9 @@ def pad_to(x: jax.Array, multiple: int, axis: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Pallas dispatch flag (thread-local so tests can flip it safely)
+# Interpret-mode override (thread-local so tests can flip it safely)
 # ---------------------------------------------------------------------------
 _state = threading.local()
-
-
-def pallas_enabled() -> bool:
-    return getattr(_state, "enabled", False)
 
 
 def interpret_mode() -> bool:
@@ -74,16 +68,6 @@ def interpret_override(value: bool):
         yield
     finally:
         _state.interpret = prev
-
-
-@contextlib.contextmanager
-def use_pallas(enabled: bool = True):
-    prev = pallas_enabled()
-    _state.enabled = enabled
-    try:
-        yield
-    finally:
-        _state.enabled = prev
 
 
 # ---------------------------------------------------------------------------

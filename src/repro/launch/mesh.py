@@ -8,27 +8,24 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh_compat", "make_production_mesh", "make_local_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh with Auto axis types where the jax version has them
-    (older releases have no AxisType and every axis is implicitly Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto (the compiler propagates
+    shardings from the constraints the model code places)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Whatever this host has, as (data, model) — for tests/examples."""
     n = len(jax.devices())
     assert n % model == 0
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -21,6 +21,7 @@ from ..configs import get_config, get_smoke
 from ..models import init_params
 from ..serving import Request, ServingEngine
 from ..tenancy import MorphableScheduler, Tenant
+from .compile_cache import enable_compile_cache
 
 
 def _occupancy_line(eng: ServingEngine) -> str:
@@ -117,6 +118,11 @@ def _run_engine(arch: str, smoke: bool, n_requests: int, max_new: int,
     for ev in eng.degraded_routes():
         print(f"[serve:{arch}] DEGRADED at step {ev['step']}: "
               f"{ev['from']} -> {ev['to']} ({ev['error']})")
+    if st.demotions:
+        # the engine kept serving on the ref route; the launcher still
+        # fails, so a broken kernel path never passes as a healthy run
+        raise SystemExit(f"[serve:{arch}] {st.demotions} route demotion(s): "
+                         f"the requested kernels did not run")
     return done
 
 
@@ -129,7 +135,9 @@ def main():
     ap.add_argument("--multi-tenant", action="store_true")
     ap.add_argument("--backend", default="auto",
                     choices=("auto", "pallas", "ref"),
-                    help="ExecutionPolicy backend plane; 'pallas' routes "
+                    help="ExecutionPolicy backend plane; 'auto' is "
+                         "'pallas' on a TPU backend and 'ref' elsewhere; "
+                         "'pallas' routes "
                          "decode-step attention to the flash-decode kernel, "
                          "chunked admission prefill to the varlen "
                          "flash-prefill kernel, and 128-aligned prefill to "
@@ -181,6 +189,7 @@ def main():
                     help="per-request wall-clock TTL in seconds")
     args = ap.parse_args()
 
+    enable_compile_cache()
     policy = api.ExecutionPolicy(format=args.format, backend=args.backend)
     priorities = ([int(x) for x in args.priority.split(",")]
                   if args.priority else None)

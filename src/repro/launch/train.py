@@ -18,6 +18,7 @@ from ..configs import get_config, get_smoke
 from ..data import DataConfig, Prefetcher, SyntheticLM
 from ..dist.sharding import set_mesh
 from ..runtime import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh
 
 
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--simulate-preemption", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(model=args.model_parallel)
     tcfg = TrainerConfig(ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

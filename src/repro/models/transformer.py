@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ssm
+from ..api.policy import policy as exec_policy
 from ..core import formats as F
 from .attention import (KVCache, PagedKVCache, PagedQuantKVCache,
                         QuantKVCache, attn_apply, attn_init,
@@ -723,10 +724,15 @@ def _sinusoid(length: int, d: int, dtype):
 def loss_fn(params, batch: Dict[str, jax.Array], cfg: ModelConfig,
             aux_weight: float = 0.01):
     """Causal-LM cross entropy (+ MoE aux). batch: tokens, labels[, frames,
-    patch_embeds]. labels = -100 masks a position out."""
-    logits, aux = forward(params, batch["tokens"], cfg,
-                          prefix_embeds=batch.get("patch_embeds"),
-                          frames=batch.get("frames"))
+    patch_embeds]. labels = -100 masks a position out.
+
+    The objective always traces the XLA reference ops: the Pallas kernels
+    are forward-only (no VJP), and on a TPU backend `backend="auto"` would
+    otherwise route 128-aligned attention to one."""
+    with exec_policy(backend="ref"):
+        logits, aux = forward(params, batch["tokens"], cfg,
+                              prefix_embeds=batch.get("patch_embeds"),
+                              frames=batch.get("frames"))
     labels = batch["labels"]
     mask = labels >= 0
     labels_safe = jnp.where(mask, labels, 0)

@@ -18,7 +18,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core import formats as F
 
@@ -62,8 +61,8 @@ def compressed_grad_allreduce(grads, err, mesh: Mesh, *, fmt_name: str = "int8",
     def one(g, e):
         spec = P(*([None] * g.ndim))
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=(spec, spec),
-                           out_specs=(spec, spec), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec),
+                           out_specs=(spec, spec), check_vma=False)
         def body(gl, el):
             x = gl.astype(jnp.float32) + el
             summed = compressed_psum(x, dp_axis, fmt)

@@ -2,11 +2,11 @@
 and bit-for-bit equivalence with the legacy per-kernel kwarg surface."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro import api
 from repro.api.registry import KernelRegistry
-from repro.kernels import common
 from repro.kernels.aio_matmul import aio_matmul
 from repro.kernels.aio_quant import aio_quantize
 from repro.kernels.depthwise import depthwise_conv
@@ -27,7 +27,7 @@ def randn(*shape, scale=1.0):
 def test_policy_defaults_and_validation():
     pol = api.ExecutionPolicy()
     assert pol.format == "bf16" and pol.backend == "auto"
-    assert not pol.use_pallas()                     # auto + flag off -> ref
+    assert not pol.use_pallas()                     # auto off a TPU -> ref
     with pytest.raises(ValueError):
         api.ExecutionPolicy(backend="cuda")
     with pytest.raises(ValueError):
@@ -52,10 +52,15 @@ def test_policy_context_nesting_inherits_unset_fields():
     assert api.current_policy() == api.default_policy
 
 
-def test_policy_auto_backend_defers_to_legacy_flag():
+def test_policy_auto_backend_defers_to_legacy_flag(monkeypatch):
+    """backend="auto" resolves from the platform alone: the kernels on a
+    TPU backend, the XLA reference path on any other."""
     assert api.ExecutionPolicy().impl() == "ref"
-    with common.use_pallas():
-        assert api.ExecutionPolicy().impl() == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert api.ExecutionPolicy().impl() == "pallas"
+    assert api.ExecutionPolicy(backend="ref").impl() == "ref"
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert api.ExecutionPolicy().impl() == "ref"
     assert api.ExecutionPolicy(backend="pallas").impl() == "pallas"
 
 

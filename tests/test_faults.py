@@ -157,6 +157,25 @@ def test_dispatch_boundary_fault_demotes_unwarmed_engine():
     assert plan.faults[0].tripped
 
 
+def test_serve_launcher_exits_nonzero_after_demotion(monkeypatch, capsys):
+    """Demotion keeps the engine serving, but the serve launcher must not
+    pass a run whose requested kernels never ran: it prints the DEGRADED
+    event and exits non-zero."""
+    from repro.launch import serve
+
+    class FaultyEngine(serve.ServingEngine):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            self.arm_fault_plan(FaultPlan.single("launch", step=0))
+
+    monkeypatch.setattr(serve, "ServingEngine", FaultyEngine)
+    with pytest.raises(SystemExit) as exc:
+        serve._run_engine("qwen2_1p5b", True, 2, 2,
+                          policy=ExecutionPolicy(backend="pallas"))
+    assert "1 route demotion(s)" in str(exc.value.code)
+    assert "DEGRADED at step 0" in capsys.readouterr().out
+
+
 def test_launch_fault_on_ref_engine_raises():
     """No route below ref: the failure propagates instead of demoting."""
     cfg, params = _params()

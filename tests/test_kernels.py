@@ -5,7 +5,7 @@ import pytest
 import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import common
+from repro import api
 from repro.kernels.aio_matmul import (aio_matmul, aio_matmul_codes,
                                       aio_matmul_ref, quantize_operands_ref)
 from repro.kernels.aio_quant import aio_quant_ref, aio_quantize
@@ -27,7 +27,8 @@ def randn(*shape, scale=1.0):
 # ======================================================================
 @pytest.mark.parametrize("mode", ["bf16", "fp8a", "fp8b", "int8", "int4"])
 @pytest.mark.parametrize("shape", [(128, 128, 128), (256, 384, 128),
-                                   (160, 200, 130), (64, 512, 96)])
+                                   (160, 200, 130), (64, 512, 96),
+                                   (96, 201, 136)])
 def test_aio_matmul_modes_shapes(mode, shape):
     m, k, n = shape
     x, w = randn(m, k), randn(k, n)
@@ -45,7 +46,7 @@ def test_aio_matmul_modes_shapes(mode, shape):
 def test_aio_matmul_dispatch_paths_agree(mode):
     x, w = randn(130, 140), randn(140, 150)
     plain = aio_matmul(x, w, mode=mode, prefer_pallas=False)
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         pall = aio_matmul(x, w, mode=mode)
     np.testing.assert_allclose(np.asarray(plain), np.asarray(pall),
                                rtol=2e-5, atol=1e-4)
@@ -85,7 +86,7 @@ def test_property_aio_matmul_random_shapes(mode, mi, ki, ni):
 def test_aio_quant_bit_exact(fmt, shape):
     x = randn(*shape, scale=13.0)
     rc, rs = aio_quant_ref(x, fmt_name=fmt)
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         pc, ps = aio_quantize(x, fmt_name=fmt)
     np.testing.assert_array_equal(np.asarray(rc).astype(np.uint8),
                                   np.asarray(pc).astype(np.uint8))
@@ -106,7 +107,7 @@ def test_grouped_matmul_vs_loop():
     x = randn(512, 200)
     w = randn(4, 200, 130)
     sizes = [128, 256, 0, 128]
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         got = np.asarray(grouped_matmul(x, w, sizes))
     xs = np.asarray(x)
     ws = np.asarray(w)
@@ -131,7 +132,7 @@ def test_property_grouped_matmul(g, ki, ni):
         t = 128
     x = randn(t, k)
     w = randn(g, k, n)
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         got = np.asarray(grouped_matmul(x, w, sizes))
     row = 0
     for gi, size in enumerate(sizes):
@@ -149,7 +150,7 @@ def test_morphable_multi_gemm_tenants():
     tenants = [(randn(100, 64), randn(64, 96)),
                (randn(300, 120), randn(120, 50)),
                (randn(60, 256), randn(256, 256))]
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         res, util = morphable_multi_gemm(tenants)
     for (x, w), r in zip(tenants, res):
         np.testing.assert_allclose(np.asarray(r),
@@ -167,7 +168,7 @@ def test_depthwise_vs_lax(shape):
     n, h, w, c, kk = shape
     x = randn(n, h, w, c)
     f = randn(kk, kk, c)
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         got = depthwise_conv(x, f)
     ref = depthwise_ref(x, f)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
@@ -180,7 +181,7 @@ def test_depthwise_vs_lax(shape):
 def test_property_depthwise(n, h, w, c, kk):
     x = randn(n, h, w, c)
     f = randn(kk, kk, c)
-    with common.use_pallas():
+    with api.policy(backend="pallas"):
         got = depthwise_conv(x, f)
     ref = depthwise_ref(x, f)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,

@@ -59,6 +59,27 @@ def test_train_step_descends(arch):
     assert float(l2) < float(l1), f"{arch}: loss did not decrease ({l1}->{l2})"
 
 
+def test_loss_fn_differentiates_where_attention_routes_to_kernels():
+    """A 128-aligned sequence routes attention to the forward-only flash
+    kernel under a pallas backend (as `backend="auto"` is on a TPU); the
+    training objective must still differentiate, through the XLA path."""
+    from repro import api
+
+    cfg = get_smoke("qwen2_1p5b")
+    params = init_params(jax.random.key(0), cfg)
+    toks = jax.random.randint(jax.random.key(1), (B, 128), 0, cfg.vocab)
+    batch = {"tokens": toks, "labels": toks}
+    assert api.ops.attention_route(lq=128, backend="pallas") == "pallas"
+    with api.policy(backend="pallas"):
+        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch, cfg)
+    (want, _), want_grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, batch, cfg)
+    assert float(loss) == float(want)
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_decode_step(arch):
     cfg = get_smoke(arch)

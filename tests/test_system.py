@@ -104,9 +104,9 @@ import sys; sys.path.insert(0, "src")
 import dataclasses, jax
 from repro.configs import SHAPES, get_smoke
 from repro.launch.dryrun import _lower_one, _costs
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 cfg = dataclasses.replace(get_smoke("qwen2_1p5b"), scan_unroll=10**6)
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cell = dataclasses.replace(SHAPES["train_4k"], batch=8, seq=64)
 c = _costs(_lower_one(cfg, cell, mesh))
 assert c["flops"] > 0 and c["bytes"] > 0, c
@@ -154,3 +154,30 @@ def test_int8_kv_cache_decode_agrees_with_bf16():
         agree += int((jnp.argmax(lf[:, -1], -1) ==
                       jnp.argmax(lq[:, -1], -1)).all())
     assert agree >= 7, agree
+
+
+def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch):
+    """The launchers' compile cache: $JAX_COMPILATION_CACHE_DIR when set
+    (and then no other directory is configured), else `.jax_cache` at the
+    root of the checkout — a fixed, git-ignored path."""
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    root = Path(__file__).resolve().parents[1]
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/deployment")
+        assert enable_compile_cache() == "/set/by/deployment"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == str(root / ".jax_cache") == enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert "/.jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        cc.reset_cache()

@@ -11,6 +11,8 @@ import pytest
 from repro import api
 from repro.configs import get_smoke
 from repro.core import formats as F
+from repro.kernels.aio_matmul import aio_matmul_ref
+from repro.kernels.aio_matmul.ops import aio_matmul_resident
 from repro.models import (QuantPolicy, init_params, quantize_params,
                           resident_format)
 from repro.models import transformer as T
@@ -103,6 +105,25 @@ def test_matmul_codes_pallas_bit_identical_to_on_the_fly_kernel(fmt, k):
     qw = F.quantize_weight(w, fmt)
     got = api.ops.matmul_codes(x, qw, backend="pallas", interpret=True)
     want = api.ops.matmul(x, w, format=fmt, backend="pallas", interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("fmt", ["int8", "int4"])
+@pytest.mark.parametrize("k", [256, 201])
+def test_matmul_codes_pallas_resident_matches_ref_oracle(fmt, k):
+    """The integer kernel path on STORED codes (int8, and int4 unpacked from
+    its two-per-byte layout in VMEM) is bit-identical to the decode-then-f32
+    oracle on the same codes — an oracle that shares no unpack or dot code
+    with the kernel."""
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(24, k).astype(np.float32))
+    w = jnp.asarray(rng.randn(k, 136).astype(np.float32))
+    qw = F.quantize_weight(w, fmt)
+    xq, xs = F.quantize_scaled(x, F.REGISTRY[fmt], axis=1, pow2=True)
+    wcodes = (F.unpack_int4(qw.codes.T, k=k).T if fmt == "int4"
+              else qw.codes)
+    want = aio_matmul_ref(xq, wcodes, xs, qw.scale.reshape(1, -1), mode=fmt)
+    got = aio_matmul_resident(xq, qw, xs, bm=128, bn=128, bk=128)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
