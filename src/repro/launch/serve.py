@@ -96,6 +96,9 @@ def _run_engine(arch: str, smoke: bool, n_requests: int, max_new: int,
     print(f"[serve:{arch}] {len(done)} requests, {toks} tokens, "
           f"{dt:.2f}s ({toks/dt:.1f} tok/s; {st.decode_steps} decode steps, "
           f"{st.prefill_chunk_calls} chunked prefills)")
+    wait_ms = 1e3 * st.queue_wait_s / st.admissions if st.admissions else 0.0
+    print(f"[serve:{arch}] admissions={st.admissions} mean queue wait "
+          f"{wait_ms:.2f} ms")
     # the fault surface: zero everywhere on a healthy run, and the first
     # place to look when outputs or latency drift
     print(f"[serve:{arch}] fault counters: quarantines={st.quarantines} "

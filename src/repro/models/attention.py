@@ -151,11 +151,13 @@ def store_pool_blocks(cache, values: dict, dst: jax.Array):
 
 
 def _q8(x: jax.Array):
-    """Per-(b, h, position) row int8 quantization with a pow2 scale."""
-    amax = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True), 1e-8)
-    scale = pow2_ceil(amax.astype(jnp.float32) / 127.0)
-    codes = jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
-                     -128, 127).astype(jnp.int8)
+    """Per-(b, h, position) row int8 quantization with a pow2 scale (the
+    int8 caches' write path)."""
+    with jax.named_scope("kv_cache"):
+        amax = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True), 1e-8)
+        scale = pow2_ceil(amax.astype(jnp.float32) / 127.0)
+        codes = jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
+                         -128, 127).astype(jnp.int8)
     return codes, scale
 
 
@@ -261,6 +263,7 @@ def attn_apply(p, x: jax.Array, *, n_heads: int, n_kv: int, causal: bool = True,
         new_pos = start + (l if lengths is None else lengths)
         keep_row = None if lengths is None else lengths > 0
 
+        @jax.named_scope("kv_cache")
         def upd(buf, new):
             if uniform:
                 # all rows at one position: a single contiguous slice write
@@ -275,6 +278,7 @@ def attn_apply(p, x: jax.Array, *, n_heads: int, n_kv: int, causal: bool = True,
         if isinstance(cache, (PagedKVCache, PagedQuantKVCache)):
             assert not uniform, "paged caches carry per-row (B,) positions"
 
+            @jax.named_scope("kv_cache")
             def pupd(pool, new):
                 return _paged_update(pool, new, start, cache.table, lengths)
 
@@ -328,8 +332,9 @@ def attn_apply(p, x: jax.Array, *, n_heads: int, n_kv: int, causal: bool = True,
         positions = jnp.arange(l)
     q = rope(q, positions, rope_theta)
     k = rope(k, positions, rope_theta)
-    out = aio_ops.attention(q, k, v, causal=causal, window=window,
-                            softcap=softcap)
+    with jax.named_scope("attention"):
+        out = aio_ops.attention(q, k, v, causal=causal, window=window,
+                                softcap=softcap)
     out = _tp(_merge_heads(out), None, "model")
     return _tp(linear(p["o"], out, policy), "model", None), None
 
@@ -343,11 +348,13 @@ def _cached_attn(q, ck, cv, start, l, causal, window, softcap,
     varlen prefill kernel prunes with it. With k_scale/v_scale, ck/cv are
     int8 codes (dequant happens at dispatch or inside the kernels)."""
     if k_scale is None:
-        ck, cv = ck.astype(q.dtype), cv.astype(q.dtype)
-    return aio_ops.attention(q, ck, cv, causal=True, window=window,
-                             softcap=softcap, offset=start, lengths=lengths,
-                             k_scale=k_scale, v_scale=v_scale,
-                             block_tables=block_tables)
+        with jax.named_scope("kv_cache"):
+            ck, cv = ck.astype(q.dtype), cv.astype(q.dtype)
+    with jax.named_scope("attention"):
+        return aio_ops.attention(q, ck, cv, causal=True, window=window,
+                                 softcap=softcap, offset=start,
+                                 lengths=lengths, k_scale=k_scale,
+                                 v_scale=v_scale, block_tables=block_tables)
 
 
 def cross_attn_apply(p, x: jax.Array, memory: jax.Array, *, n_heads: int,
@@ -356,5 +363,6 @@ def cross_attn_apply(p, x: jax.Array, memory: jax.Array, *, n_heads: int,
     q = _split_heads(linear(p["q"], x, policy), n_heads)
     k = _split_heads(linear(p["k"], memory, policy), n_kv)
     v = _split_heads(linear(p["v"], memory, policy), n_kv)
-    out = aio_ops.attention(q, k, v, causal=False)
+    with jax.named_scope("attention"):
+        out = aio_ops.attention(q, k, v, causal=False)
     return linear(p["o"], _merge_heads(out), policy)

@@ -88,13 +88,16 @@ def linear(p, x: jax.Array, policy: QuantPolicy = QuantPolicy()) -> jax.Array:
         # op decodes tiles in VMEM (pallas) or dequantizes at dispatch (ref)
         from ..api import ops as aio_ops        # deferred: api ships no models
         x = _maybe_quant(x, policy.activations)
-        y = aio_ops.matmul_codes(x, w).astype(x.dtype)
+        with jax.named_scope("linear"):
+            y = aio_ops.matmul_codes(x, w).astype(x.dtype)
     else:
         if policy.active:
             x = _maybe_quant(x, policy.activations)
-            w = _maybe_quant_weight(w, policy.weights)
-        y = jnp.einsum("...d,df->...f", x, w,
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+            with jax.named_scope("weight_cast"):
+                w = _maybe_quant_weight(w, policy.weights)
+        with jax.named_scope("linear"):
+            y = jnp.einsum("...d,df->...f", x, w,
+                           preferred_element_type=jnp.float32).astype(x.dtype)
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     return y

@@ -703,6 +703,7 @@ def forward(params, tokens: jax.Array, cfg: ModelConfig, *,
     return logits, aux_total
 
 
+@jax.named_scope("unembed")
 def _unembed(params, x, cfg: ModelConfig):
     if cfg.tie_embeddings:
         logits = jnp.einsum("bld,vd->blv", x, params["embed"]["table"],

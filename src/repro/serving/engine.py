@@ -94,6 +94,14 @@ All of it is exercised by `repro.serving.faults` — a seeded, deterministic
 fault-injection plan armed via `arm_fault_plan()`; production pays zero cost
 when no plan is armed (one `is None` check per step).
 
+Under the JAX profiler each step writes host spans into the trace, on the
+device's clock: `engine.step`, and inside it `engine.admit`,
+`engine.launch` (a launch's inputs and dispatch), `engine.sync` (the host
+blocking on the tokens and the health flags) and `engine.emit` (the token
+bookkeeping). With the profiler off a span costs about a microsecond.
+`EngineStats.admissions` / `queue_wait_s` count each request's wait from
+`submit()` to its first slot.
+
 Multi-tenant serving stacks one engine per tenant on its mesh partition
 (tenancy/scheduler.py — the §VI-C scenario); engines report per-slot
 occupancy through `occupancy()` for the scheduler's utilization view.
@@ -106,7 +114,7 @@ import dataclasses
 import hashlib
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -128,6 +136,16 @@ _RECURRENT_KINDS = ("mamba", "mlstm", "slstm")
 
 # Request.status values once a request leaves the engine for good.
 TERMINAL_STATES = ("done", "TIMEOUT", "REJECTED", "FAILED")
+
+
+def _span(name: str, args: Optional[Callable[[], dict]] = None):
+    """A host span in the profiler's trace (`jax.profiler.TraceAnnotation`),
+    on the device trace's clock. `args` gives the span's arguments and is
+    called only while the profiler records: with it off a span costs about
+    a microsecond and builds no argument."""
+    if args is not None and jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name, **args())
+    return jax.profiler.TraceAnnotation(name)
 
 
 def _encode_memory(params, frames, cfg):
@@ -193,6 +211,9 @@ class EngineStats:
     preemptions: int = 0              # resident rows preempted under pressure
     swap_outs: int = 0                # preemptions that moved blocks to host
     swap_ins: int = 0                 # preempted rows restored byte-identically
+    # --- admission: a request's first slot, and its wait for it ---
+    admissions: int = 0               # queued requests given their first slot
+    queue_wait_s: float = 0.0         # sum over those of slot minus submit()
 
     @property
     def model_calls(self) -> int:
@@ -386,7 +407,8 @@ class ServingEngine:
         launch or a host round-trip (`repro.analysis` HL205 pins this)."""
         logits, caches = T.decode_step(p, c, t, self.cfg, memory=m,
                                        lengths=lens)
-        health = jnp.all(jnp.isfinite(logits), axis=(1, 2))
+        with jax.named_scope("health"):
+            health = jnp.all(jnp.isfinite(logits), axis=(1, 2))
         return logits, caches, health
 
     def _build_step_fns(self):
@@ -495,8 +517,9 @@ class ServingEngine:
         if self._paged:
             self._pg_release_row(slot)
 
-    def _admit(self, newly_finished: List[Request]):
-        """Assign queued requests to free slots and reset their cache rows.
+    def _admit(self, newly_finished: List[Request]) -> List[int]:
+        """Assign queued requests to free slots and reset their cache rows;
+        returns the slots filled.
         NO model call happens here — the prompts advance chunk by chunk in
         subsequent step()s, interleaved with everyone else's decode.
 
@@ -575,6 +598,10 @@ class ServingEngine:
                         cow_dst.append(dst)
                 req.status = "active"
                 self._slot_req[s] = req
+                if req.replays == 0:
+                    self.stats.admissions += 1
+                    self.stats.queue_wait_s += time.monotonic() - \
+                        req._submit_t
                 self._prefilling[s] = True
                 self._prefill_off[s] = covered
                 self._remaining[s] = req.max_new_tokens
@@ -606,6 +633,7 @@ class ServingEngine:
                     self.stats.swap_ins += 1
             else:
                 self.caches = self._reset_fn(self.caches, jnp.asarray(reset))
+        return admitted
 
     # ------------------------------------------------------ paged block pool
     def _pg_key(self, prompt: np.ndarray) -> str:
@@ -1272,23 +1300,24 @@ class ServingEngine:
         the real count); decoding/free rows ride along with lengths == 0 and
         keep their caches untouched."""
         c = self.prefill_chunk
-        toks = np.full((self.slots, c), PAD, np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        finishing = []
-        for s, r in enumerate(self._slot_req):
-            if r is None or not self._prefilling[s]:
-                continue
-            off = int(self._prefill_off[s])
-            take = min(c, len(r.prompt) - off)
-            toks[s, :take] = r.prompt[off:off + take]
-            lens[s] = take
-            if off + take >= len(r.prompt):
-                finishing.append(s)
-        consumed = np.zeros(self.slots, bool)
-        consumed[finishing] = True
-        logits, health_dev = self._launch(jnp.asarray(toks),
-                                          jnp.asarray(lens),
-                                          consumed=consumed)
+        with _span("engine.launch", lambda: {"kind": "chunk"}):
+            toks = np.full((self.slots, c), PAD, np.int32)
+            lens = np.zeros(self.slots, np.int32)
+            finishing = []
+            for s, r in enumerate(self._slot_req):
+                if r is None or not self._prefilling[s]:
+                    continue
+                off = int(self._prefill_off[s])
+                take = min(c, len(r.prompt) - off)
+                toks[s, :take] = r.prompt[off:off + take]
+                lens[s] = take
+                if off + take >= len(r.prompt):
+                    finishing.append(s)
+            consumed = np.zeros(self.slots, bool)
+            consumed[finishing] = True
+            logits, health_dev = self._launch(jnp.asarray(toks),
+                                              jnp.asarray(lens),
+                                              consumed=consumed)
         self.stats.prefill_chunk_calls += 1
         self.stats.prefill_tokens += int(lens.sum())
         bad = np.zeros(self.slots, bool)
@@ -1299,22 +1328,24 @@ class ServingEngine:
             # the NaN has propagated through attention). Gather + argmax run
             # ON DEVICE: only (slots,) int32 crosses to host, never a logits
             # block
-            idx = jnp.asarray(np.clip(lens - 1, 0, c - 1))
-            last = jnp.take_along_axis(logits, idx[:, None, None],
-                                       axis=1)[:, 0]
-            first_tok = np.asarray(jnp.argmax(last, axis=-1))
-            bad = self._occupied() & ~np.asarray(health_dev)
-        for s, r in enumerate(self._slot_req):
-            if r is None or not self._prefilling[s]:
-                continue
-            self._prefill_off[s] += lens[s]
-        if bad.any():
-            self._quarantine(np.flatnonzero(bad), newly)
-        for s in finishing:
-            if bad[s] or self._slot_req[s] is None:
-                continue
-            self._prefilling[s] = False
-            self._emit_first(s, int(first_tok[s]), newly)
+            with _span("engine.sync"):
+                idx = jnp.asarray(np.clip(lens - 1, 0, c - 1))
+                last = jnp.take_along_axis(logits, idx[:, None, None],
+                                           axis=1)[:, 0]
+                first_tok = np.asarray(jnp.argmax(last, axis=-1))
+                bad = self._occupied() & ~np.asarray(health_dev)
+        with _span("engine.emit"):
+            for s, r in enumerate(self._slot_req):
+                if r is None or not self._prefilling[s]:
+                    continue
+                self._prefill_off[s] += lens[s]
+            if bad.any():
+                self._quarantine(np.flatnonzero(bad), newly)
+            for s in finishing:
+                if bad[s] or self._slot_req[s] is None:
+                    continue
+                self._prefilling[s] = False
+                self._emit_first(s, int(first_tok[s]), newly)
 
     def _decode_launch(self, newly: List[Request]):
         """ONE batched decode launch for every mid-generation slot;
@@ -1324,57 +1355,62 @@ class ServingEngine:
              for s, r in enumerate(self._slot_req)])
         if not active.any():
             return
-        logits, health_dev = self._launch(
-            jnp.asarray(self._last), jnp.asarray(active.astype(np.int32)),
-            consumed=active)
+        with _span("engine.launch", lambda: {"kind": "decode"}):
+            logits, health_dev = self._launch(
+                jnp.asarray(self._last), jnp.asarray(active.astype(np.int32)),
+                consumed=active)
         self.stats.decode_steps += 1
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1)).astype(np.int32)
         # the guard consumes health at this already-syncing point: any
         # occupied row gone non-finite (its own logits, or a poisoned cache
         # surfacing through a ride-along row) is quarantined, its token
         # never emitted
-        bad = self._occupied() & ~np.asarray(health_dev)
-        if bad.any():
-            self._quarantine(np.flatnonzero(bad), newly)
-        for s in range(self.slots):
-            req = self._slot_req[s]
-            if req is None or not active[s] or bad[s]:
-                continue
-            tok = int(nxt[s])
-            req.out_tokens.append(tok)
-            self.stats.generated_tokens += 1
-            self._remaining[s] -= 1
-            if self._remaining[s] <= 0 or (self.eos_id is not None
-                                           and tok == self.eos_id):
-                self._finish(s)
-                newly.append(req)
-            else:
-                self._last[s, 0] = tok
+        with _span("engine.sync"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1],
+                                        axis=-1)).astype(np.int32)
+            bad = self._occupied() & ~np.asarray(health_dev)
+        with _span("engine.emit"):
+            if bad.any():
+                self._quarantine(np.flatnonzero(bad), newly)
+            for s in range(self.slots):
+                req = self._slot_req[s]
+                if req is None or not active[s] or bad[s]:
+                    continue
+                tok = int(nxt[s])
+                req.out_tokens.append(tok)
+                self.stats.generated_tokens += 1
+                self._remaining[s] -= 1
+                if self._remaining[s] <= 0 or (self.eos_id is not None
+                                               and tok == self.eos_id):
+                    self._finish(s)
+                    newly.append(req)
+                else:
+                    self._last[s, 0] = tok
 
     def _merged_step(self, newly: List[Request]):
         """Recurrent archs / chunk=1: ONE l=1 launch advances everything —
         prefilling rows feed their next prompt token, decoding rows their
         last sampled one. Counted as a decode step when any row decoded,
         else as a prefill token step."""
-        toks = np.full((self.slots, 1), PAD, np.int32)
-        lens = np.zeros(self.slots, np.int32)
-        consumed = np.zeros(self.slots, bool)
-        n_prefill = n_decode = 0
-        for s, r in enumerate(self._slot_req):
-            if r is None:
-                continue
-            lens[s] = 1
-            if self._prefilling[s]:
-                toks[s, 0] = r.prompt[int(self._prefill_off[s])]
-                consumed[s] = self._prefill_off[s] + 1 >= len(r.prompt)
-                n_prefill += 1
-            else:
-                toks[s, 0] = self._last[s, 0]
-                consumed[s] = True
-                n_decode += 1
-        logits, health_dev = self._launch(jnp.asarray(toks),
-                                          jnp.asarray(lens),
-                                          consumed=consumed)
+        with _span("engine.launch", lambda: {"kind": "merged"}):
+            toks = np.full((self.slots, 1), PAD, np.int32)
+            lens = np.zeros(self.slots, np.int32)
+            consumed = np.zeros(self.slots, bool)
+            n_prefill = n_decode = 0
+            for s, r in enumerate(self._slot_req):
+                if r is None:
+                    continue
+                lens[s] = 1
+                if self._prefilling[s]:
+                    toks[s, 0] = r.prompt[int(self._prefill_off[s])]
+                    consumed[s] = self._prefill_off[s] + 1 >= len(r.prompt)
+                    n_prefill += 1
+                else:
+                    toks[s, 0] = self._last[s, 0]
+                    consumed[s] = True
+                    n_decode += 1
+            logits, health_dev = self._launch(jnp.asarray(toks),
+                                              jnp.asarray(lens),
+                                              consumed=consumed)
         if n_decode:
             self.stats.decode_steps += 1
         else:
@@ -1383,30 +1419,33 @@ class ServingEngine:
         # argmax ON DEVICE: only (slots,) int32 crosses to host — the first
         # token of a finishing prefill row IS its argmax, same as a decode
         # row's, so one vector serves both
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1)).astype(np.int32)
-        bad = self._occupied() & ~np.asarray(health_dev)
-        if bad.any():
-            self._quarantine(np.flatnonzero(bad), newly)
-        for s in range(self.slots):
-            req = self._slot_req[s]
-            if req is None or bad[s]:
-                continue
-            if self._prefilling[s]:
-                self._prefill_off[s] += 1
-                if self._prefill_off[s] >= len(req.prompt):
-                    self._prefilling[s] = False
-                    self._emit_first(s, int(nxt[s]), newly)
-                continue
-            tok = int(nxt[s])
-            req.out_tokens.append(tok)
-            self.stats.generated_tokens += 1
-            self._remaining[s] -= 1
-            if self._remaining[s] <= 0 or (self.eos_id is not None
-                                           and tok == self.eos_id):
-                self._finish(s)
-                newly.append(req)
-            else:
-                self._last[s, 0] = tok
+        with _span("engine.sync"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0],
+                                        axis=-1)).astype(np.int32)
+            bad = self._occupied() & ~np.asarray(health_dev)
+        with _span("engine.emit"):
+            if bad.any():
+                self._quarantine(np.flatnonzero(bad), newly)
+            for s in range(self.slots):
+                req = self._slot_req[s]
+                if req is None or bad[s]:
+                    continue
+                if self._prefilling[s]:
+                    self._prefill_off[s] += 1
+                    if self._prefill_off[s] >= len(req.prompt):
+                        self._prefilling[s] = False
+                        self._emit_first(s, int(nxt[s]), newly)
+                    continue
+                tok = int(nxt[s])
+                req.out_tokens.append(tok)
+                self.stats.generated_tokens += 1
+                self._remaining[s] -= 1
+                if self._remaining[s] <= 0 or (self.eos_id is not None
+                                               and tok == self.eos_id):
+                    self._finish(s)
+                    newly.append(req)
+                else:
+                    self._last[s, 0] = tok
 
     # --------------------------------------------------------------- driving
     def step(self) -> List[Request]:
@@ -1416,23 +1455,28 @@ class ServingEngine:
         Returns the requests that finished during this step (including ones
         that TIMED OUT or FAILED). The step counter advances on every call,
         busy or idle."""
-        newly: List[Request] = []
-        plan = self._fault_plan
-        if plan is not None:
-            self._inject_pre_step(plan, self._step_no)
-        if self._paged and self._pg_holds:
-            self._pg_release_pressure()
-        if self._has_deadlines:
-            self._expire_deadlines(newly)
-        self._admit(newly)
-        if any(r is not None for r in self._slot_req):
-            if self._merged_mode():
-                self._merged_step(newly)
-            else:
-                if self._prefilling.any():
-                    self._prefill_chunk_step(newly)
-                self._decode_launch(newly)
-        self._step_no += 1
+        with _span("engine.step", lambda: {"step": self._step_no}):
+            newly: List[Request] = []
+            plan = self._fault_plan
+            if plan is not None:
+                self._inject_pre_step(plan, self._step_no)
+            if self._paged and self._pg_holds:
+                self._pg_release_pressure()
+            if self._has_deadlines:
+                self._expire_deadlines(newly)
+            with _span("engine.admit") as span:
+                admitted = self._admit(newly)
+                if admitted and span.is_enabled():
+                    span.set_metadata(rids=" ".join(
+                        str(self._slot_req[s].rid) for s in admitted))
+            if any(r is not None for r in self._slot_req):
+                if self._merged_mode():
+                    self._merged_step(newly)
+                else:
+                    if self._prefilling.any():
+                        self._prefill_chunk_step(newly)
+                    self._decode_launch(newly)
+            self._step_no += 1
         return newly
 
     def pending(self) -> bool:
@@ -1669,6 +1713,18 @@ class ServingEngine:
         with self._policy_ctx():
             return jax.make_jaxpr(self._step_program)(
                 self.params, self.caches, tok, lens, self.memory)
+
+    def step_hlo(self, width: int) -> str:
+        """Compiled HLO text of the step program at token width `width`.
+        Each instruction's `metadata={op_name=...}` carries the model's
+        named scopes (`kv_cache`, `attention`, `linear`, ...), which is how
+        a profiler trace's device ops are attributed to them. Compiles
+        unless the persistent compile cache holds the program."""
+        tok = jnp.zeros((self.slots, width), jnp.int32)
+        lens = jnp.zeros((self.slots,), jnp.int32)
+        with self._policy_ctx():
+            return self._step_fn.lower(self.params, self.caches, tok, lens,
+                                       self.memory).compile().as_text()
 
     def donated_avals(self) -> list:
         """(shape, dtype) of every leaf the step donates (the cache pytree),
