@@ -174,7 +174,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
               bq: Optional[int] = None, backend: Optional[str] = None,
               interpret: Optional[bool] = None,
               block_tables: Optional[jax.Array] = None,
-              policy: Optional[ExecutionPolicy] = None) -> jax.Array:
+              k_new: Optional[jax.Array] = None,
+              v_new: Optional[jax.Array] = None, layer=None,
+              policy: Optional[ExecutionPolicy] = None):
     """GQA attention. q: (B,Hq,Lq,D); k,v: (B,Hkv,Lk,D).
 
     offset: scalar or per-row (B,) cache position (continuous batching:
@@ -192,20 +194,35 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     `attention_route` for which shapes hit "pallas" (full-sequence flash),
     "pallas-prefill" (varlen chunk prefill), "pallas-decode"
     (flash-decode), or "ref".
+
+    k_new/v_new (B,Hkv,Lq,D): the cache is written as well as read. k and
+    v are then the stacked (n_layers,B,Hkv,Lk,D) per-slot caches, `layer`
+    picks the one attended, and row b's first lengths[b] new rows (all Lq
+    when lengths is None) are written at offset[b].. first, rounded to the
+    cache dtype. Returns (out, k, v): the serving kernels update the cache
+    in place, the ref path scatters the rows. offset is per-row here.
     """
     pol = _resolve(policy, backend=backend, chunk=chunk, bkv=bkv, bq=bq,
                    interpret=interpret)
-    lk = k.shape[2] if block_tables is None \
-        else block_tables.shape[1] * k.shape[2]
+    if k_new is not None:
+        lk = k.shape[3]
+        offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32),
+                                  (q.shape[0],))
+    elif block_tables is None:
+        lk = k.shape[2]
+    else:
+        lk = block_tables.shape[1] * k.shape[2]
     impl = attention_route(lq=q.shape[2], lk=lk, causal=causal,
                            offset_ndim=jnp.ndim(offset),
                            quantized=k_scale is not None, policy=pol)
     if block_tables is not None and impl == "pallas":
         impl = "ref"    # no paged route on the full-sequence kernel
+    update = {} if k_new is None else \
+        {"k_new": k_new, "v_new": v_new, "layer": layer}
     return _dispatch("attention", impl, pol, q, k, v, causal=causal,
                      window=window, softcap=softcap, scale=scale,
                      offset=offset, lengths=lengths, k_scale=k_scale,
-                     v_scale=v_scale, block_tables=block_tables)
+                     v_scale=v_scale, block_tables=block_tables, **update)
 
 
 def depthwise_conv(x: jax.Array, filt: jax.Array, *, bh: Optional[int] = None,

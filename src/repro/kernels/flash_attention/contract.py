@@ -1,12 +1,16 @@
 """Launch contracts for the three flash-attention pallas impls.
 
 These reuse the REAL index-map factories (`flash_index_maps`,
-`decode_index_maps`, `prefill_index_maps`) — the GQA head mapping and the
-per-row block-pruning clamps are exactly the functions a production launch
-installs, evaluated here out-of-trace over concrete (pos, lengths) vectors.
-The decode/prefill clamps are load-bearing: an off-by-one in `_block_bounds`
-or `_kv_bounds` is an out-of-bounds DMA on hardware, which is what the
-KC102 sweep exists to catch.
+`decode_index_maps`, `prefill_index_maps`, and the in-place launches'
+`update_decode_index_maps` / `update_prefill_index_maps`) — the GQA head
+mapping and the per-row block-pruning clamps are exactly the functions a
+production launch installs, evaluated here out-of-trace over concrete
+(pos, lengths, layer) vectors. The decode/prefill clamps are load-bearing:
+an off-by-one in `_block_bounds` or `_kv_bounds` is an out-of-bounds DMA on
+hardware, which is what the KC102 sweep exists to catch. The in-place
+launches add the layer offset into the stacked cache and the output that
+writes the frontier block(s) back; their KB4xx body proofs cover that
+write.
 """
 from __future__ import annotations
 
@@ -18,12 +22,13 @@ from ...api.registry import BlockContract, LaunchContract, register_contract
 from ..common import ceil_div
 from .decode import (decode_index_maps, flash_decode_paged_pallas,
                      flash_decode_paged_quant_pallas, flash_decode_pallas,
-                     flash_decode_quant_pallas, paged_decode_index_maps)
+                     flash_decode_quant_pallas, paged_decode_index_maps,
+                     update_decode_index_maps)
 from .kernel import flash_attention_pallas, flash_index_maps
 from .prefill import (flash_prefill_paged_pallas,
                       flash_prefill_paged_quant_pallas, flash_prefill_pallas,
                       flash_prefill_quant_pallas, paged_prefill_index_maps,
-                      prefill_index_maps)
+                      prefill_index_maps, update_prefill_index_maps)
 
 __all__ = ["attention_contract", "decode_contract", "prefill_contract"]
 
@@ -119,7 +124,32 @@ _DECODE_CASES = (
     {"b": 2, "hq": 8, "hkv": 2, "lq": 4, "d": 64, "paged": True,
      "bs": 16, "nblk": 8, "pool": 18, "pos": (12, 124), "window": None,
      "quant": True},
+    # in place: a stacked 3-layer cache, layer 2 (the last) read and
+    # written; a row sitting the step out (lengths 0) and the cache's end
+    {"b": 3, "hq": 4, "hkv": 2, "lq": 1, "lk": 640, "d": 64, "inplace": True,
+     "layers": 3, "layer": 2, "pos": (0, 37, 639), "lens": (1, 0, 1),
+     "window": None, "quant": False},
+    {"b": 3, "hq": 4, "hkv": 2, "lq": 1, "lk": 600, "d": 64, "inplace": True,
+     "layers": 3, "layer": 0, "pos": (0, 130, 599), "lens": (1, 1, 1),
+     "window": 64, "quant": False},
 )
+
+
+def _stacked_cache_blocks(rows, lk, bkv, d, kv_index, out_kv_index, *,
+                          revisits):
+    """The in-place launches' stacked-cache operands: K/V read through
+    `kv_index`, and the aliased K/V outputs that write the frontier
+    block(s) back through `out_kv_index` (both over the
+    (n_layers*rows, Lk, D) cache; an unaligned Lk's last block is masked)."""
+    tail = lk % bkv != 0
+    shape = (rows, lk, d)
+    return ([BlockContract(n, shape, (1, bkv, d), kv_index,
+                           dtype_bytes=_BF16, masked_tail=tail)
+             for n in ("k", "v")],
+            [BlockContract(n, shape, (1, bkv, d), out_kv_index,
+                           dtype_bytes=_BF16, masked_tail=tail,
+                           is_output=True, revisits=revisits)
+             for n in ("k_out", "v_out")])
 
 
 @register_contract("attention", "pallas-decode", cases=_DECODE_CASES,
@@ -127,6 +157,8 @@ _DECODE_CASES = (
 def decode_contract(case: dict, policy: ExecutionPolicy) -> LaunchContract:
     if case.get("paged"):
         return _paged_decode_contract(case)
+    if case.get("inplace"):
+        return _inplace_decode_contract(case, policy)
     b, hq, hkv = case["b"], case["hq"], case["hkv"]
     lq, lk, d = case["lq"], case["lk"], case["d"]
     bkv = policy.bkv
@@ -163,6 +195,52 @@ def decode_contract(case: dict, policy: ExecutionPolicy) -> LaunchContract:
         num_scalar_prefetch=1,
         scalars=(pos,),
         scratch_bytes=(gl + gl + gl * d) * 4,
+        body=body,
+    )
+
+
+def _inplace_decode_contract(case: dict,
+                             policy: ExecutionPolicy) -> LaunchContract:
+    """The in-place decode launch: grid (row-head, KV block) over layer
+    `layer` of a stacked cache; the K/V outputs stay on each row's frontier
+    block across the KV loop (grid dim 1) and write it back once."""
+    b, hq, hkv = case["b"], case["hq"], case["hkv"]
+    lk, d, n = case["lk"], case["d"], case["layers"]
+    bkv = min(policy.bkv, lk)                   # _update_launch's rule
+    group, rows, nk = hq // hkv, b * hkv, ceil_div(lk, bkv)
+    pos = np.asarray(case["pos"], np.int32)
+    lens = np.asarray(case["lens"], np.int32)
+    layer = np.asarray([case["layer"]], np.int32)
+    q_index, kv_index, out_kv_index = update_decode_index_maps(
+        hkv=hkv, bkv=bkv, nk=nk, rows=rows, window=case["window"])
+    kv_in, kv_out = _stacked_cache_blocks(n * rows, lk, bkv, d, kv_index,
+                                          out_kv_index, revisits=(1,))
+    blocks = [BlockContract("q", (rows, group, d), (1, group, d), q_index,
+                            dtype_bytes=_BF16)]
+    blocks += kv_in
+    blocks += [BlockContract(nm, (rows, 1, d), (1, 1, d), q_index,
+                             dtype_bytes=_BF16) for nm in ("k_new", "v_new")]
+    blocks.append(BlockContract("out", (rows, group, d), (1, group, d),
+                                q_index, dtype_bytes=_BF16, is_output=True,
+                                revisits=(1,)))
+    blocks += kv_out
+
+    def body():
+        q = jnp.zeros((b, hq, 1, d), jnp.bfloat16)
+        kv = jnp.zeros((n, b, hkv, lk, d), jnp.bfloat16)
+        new = jnp.zeros((b, hkv, 1, d), jnp.bfloat16)
+        return flash_decode_pallas(q, kv, kv, pos=jnp.asarray(pos),
+                                   window=case["window"], bkv=bkv,
+                                   k_new=new, v_new=new,
+                                   layer=jnp.asarray(case["layer"]),
+                                   lengths=jnp.asarray(lens))
+
+    return LaunchContract(
+        grid=(rows, nk),
+        blocks=tuple(blocks),
+        num_scalar_prefetch=3,
+        scalars=(pos, lens, layer),
+        scratch_bytes=(group + group + group * d) * 4,
         body=body,
     )
 
@@ -230,6 +308,15 @@ _PREFILL_CASES = (
     {"b": 2, "hq": 8, "hkv": 2, "lq": 48, "d": 64, "paged": True,
      "bs": 16, "nblk": 8, "pool": 18, "pos": (80, 0), "lens": (48, 1),
      "window": None, "quant": True},
+    # in place over a stacked 3-layer cache: chunks that cross a block
+    # boundary, fill the cache's last block, sit the step out, or end in
+    # a final partial chunk
+    {"b": 4, "hq": 4, "hkv": 2, "lq": 64, "lk": 384, "d": 64,
+     "inplace": True, "layers": 3, "layer": 2, "pos": (0, 100, 320, 200),
+     "lens": (64, 40, 64, 0), "window": None, "quant": False},
+    {"b": 3, "hq": 4, "hkv": 2, "lq": 32, "lk": 300, "d": 64,
+     "inplace": True, "layers": 2, "layer": 1, "pos": (250, 120, 0),
+     "lens": (32, 9, 1), "window": 64, "quant": False},
 )
 
 
@@ -238,6 +325,8 @@ _PREFILL_CASES = (
 def prefill_contract(case: dict, policy: ExecutionPolicy) -> LaunchContract:
     if case.get("paged"):
         return _paged_prefill_contract(case, policy)
+    if case.get("inplace"):
+        return _inplace_prefill_contract(case, policy)
     b, hq, hkv = case["b"], case["hq"], case["hkv"]
     lq, lk, d = case["lq"], case["lk"], case["d"]
     bq = max(1, min(policy.bq, lq))             # _prep's resolution rule
@@ -280,6 +369,58 @@ def prefill_contract(case: dict, policy: ExecutionPolicy) -> LaunchContract:
         blocks=tuple(blocks),
         num_scalar_prefetch=2,
         scalars=(pos, lens),
+        scratch_bytes=(group * bq * 2 + group * bq * d) * 4,
+        body=body,
+    )
+
+
+def _inplace_prefill_contract(case: dict,
+                              policy: ExecutionPolicy) -> LaunchContract:
+    """The in-place varlen-prefill launch over layer `layer` of a stacked
+    cache: the chunk's new rows are one whole block read at every grid
+    step; the K/V outputs walk `_write_blocks` (the frontier block(s),
+    revisited along the q-block and KV loops, grid dims 1 and 2)."""
+    b, hq, hkv = case["b"], case["hq"], case["hkv"]
+    lq, lk, d, n = case["lq"], case["lk"], case["d"], case["layers"]
+    bq = max(1, min(policy.bq, lq))             # _prep's resolution rule
+    bkv = min(policy.bkv, lk)                   # _update_launch's rule
+    group, rows = hq // hkv, b * hkv
+    lq_pad = ceil_div(lq, bq) * bq
+    nk = ceil_div(lk, bkv)
+    pos = np.asarray(case["pos"], np.int32)
+    lens = np.asarray(case["lens"], np.int32)
+    layer = np.asarray([case["layer"]], np.int32)
+    q_index, o_index, new_index, kv_index, out_kv_index = \
+        update_prefill_index_maps(bq=bq, bkv=bkv, nk=nk, hkv=hkv, rows=rows,
+                                  window=case["window"])
+    kv_in, kv_out = _stacked_cache_blocks(n * rows, lk, bkv, d, kv_index,
+                                          out_kv_index, revisits=(1, 2))
+    blocks = [BlockContract("q", (rows, group, lq_pad, d), (1, group, bq, d),
+                            q_index, dtype_bytes=_BF16)]
+    blocks += kv_in
+    blocks += [BlockContract(nm, (rows, lq_pad, d), (1, lq_pad, d),
+                             new_index, dtype_bytes=_BF16)
+               for nm in ("k_new", "v_new")]
+    blocks.append(BlockContract("out", (rows, group, lq_pad, d),
+                                (1, group, bq, d), o_index, dtype_bytes=_BF16,
+                                is_output=True, revisits=(2,)))
+    blocks += kv_out
+
+    def body():
+        q = jnp.zeros((b, hq, lq, d), jnp.bfloat16)
+        kv = jnp.zeros((n, b, hkv, lk, d), jnp.bfloat16)
+        new = jnp.zeros((b, hkv, lq, d), jnp.bfloat16)
+        return flash_prefill_pallas(q, kv, kv, pos=jnp.asarray(pos),
+                                    lengths=jnp.asarray(lens),
+                                    window=case["window"], bq=policy.bq,
+                                    bkv=bkv, k_new=new, v_new=new,
+                                    layer=jnp.asarray(case["layer"]))
+
+    return LaunchContract(
+        grid=(rows, lq_pad // bq, nk),
+        blocks=tuple(blocks),
+        num_scalar_prefetch=3,
+        scalars=(pos, lens, layer),
         scratch_bytes=(group * bq * 2 + group * bq * d) * 4,
         body=body,
     )

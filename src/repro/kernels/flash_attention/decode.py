@@ -18,7 +18,13 @@ and a scalar offset). This kernel is specialized for it:
   * a fused INT8-KV variant takes `(codes, pow2 scale)` and dequantizes in
     VMEM — the full-cache dequant materialization in HBM disappears. The
     in-kernel dequant rounds through `cast_dtype` (the q dtype) so it is
-    bit-identical to dequantize-then-dense-kernel.
+    bit-identical to dequantize-then-dense-kernel;
+  * an IN-PLACE variant (`k_new`/`v_new` given) takes the stacked bf16
+    cache of every layer plus the step's new rows, reads layer `layer`
+    through its index map (the layer is one more scalar prefetch), merges
+    the new row into the frontier block in VMEM, attends over it, and
+    writes that one block back through an output aliased to the cache.
+    No whole-cache cast, select or copy is left around the launch.
 
 Validated in interpret mode against ref.mha_ref (tests/test_decode_kernel.py).
 """
@@ -32,14 +38,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import interpret_mode, pad_to
+from ..common import ceil_div, interpret_mode, pad_to
 from .shared import NEG_INF as _NEG_INF
-from .shared import as_row_vector, vmem_dequant
+from .shared import as_row_vector, merge_rows, vmem_dequant, zero_tail
 
 __all__ = ["flash_decode_pallas", "flash_decode_quant_pallas",
            "flash_decode_paged_pallas", "flash_decode_paged_quant_pallas",
            "decode_block_visits", "decode_index_maps",
-           "paged_decode_index_maps"]
+           "paged_decode_index_maps", "update_decode_index_maps"]
 
 
 def _block_bounds(start, lq: int, window: Optional[int], bkv: int):
@@ -115,6 +121,43 @@ def _dense_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *rest, debug_visits,
                   o_ref, visits_ref, m_ref, l_ref, acc_ref, **kw)
 
 
+def _frontier_block(start, bkv: int, nk: int):
+    """The KV block that holds a row's new token at position `start`
+    (clamped into the cache for a row idling at its end)."""
+    return jnp.minimum(start // bkv, nk - 1)
+
+
+def _update_kernel(pos_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, kn_ref,
+                   vn_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref, *,
+                   bkv: int, nk: int, lk_real: int, hkv: int, **kw):
+    """In-place decode: the new row merges into the frontier block, which
+    is written to the aliased cache output and attended from there."""
+    ik = pl.program_id(1)
+    row = pl.program_id(0) // hkv
+    start = pos_ref[row]
+    here = ik == _frontier_block(start, bkv, nk)
+
+    @pl.when(here)
+    def _write():
+        # lengths[row] == 0 merges nothing: the block goes back unchanged
+        ko_ref[0] = merge_rows(k_ref[0], kn_ref[0], ik * bkv, start,
+                               len_ref[row])
+        vo_ref[0] = merge_rows(v_ref[0], vn_ref[0], ik * bkv, start,
+                               len_ref[row])
+
+    def load(ref, out_ref):
+        def get():
+            blk = jax.lax.cond(here, lambda: out_ref[0], lambda: ref[0])
+            if lk_real % bkv:
+                blk = zero_tail(blk, ik * bkv, lk_real)
+            return blk.astype(jnp.float32)
+        return get
+
+    _online_block(pos_ref, q_ref, load(k_ref, ko_ref), load(v_ref, vo_ref),
+                  o_ref, None, m_ref, l_ref, acc_ref, bkv=bkv, nk=nk,
+                  lk_real=lk_real, hkv=hkv, **kw)
+
+
 def _quant_kernel(pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
                   *rest, debug_visits, cast_dtype, **kw):
     visits_ref, (m_ref, l_ref, acc_ref) = \
@@ -144,6 +187,29 @@ def decode_index_maps(*, lq: int, hkv: int, bkv: int,
         return (bh, jnp.clip(ik, first, last), 0)
 
     return q_index, kv_index
+
+
+def update_decode_index_maps(*, hkv: int, bkv: int, nk: int, rows: int,
+                             window: Optional[int]):
+    """The index maps of an in-place decode launch (Lq = 1): q, the
+    stacked cache's K/V read, the step's new rows, and the aliased cache
+    output. The cache is laid out (n_layers*rows, Lk, D), so row-head bh of
+    layer i is row i*rows + bh; the layer rides scalar prefetch after the
+    positions and lengths. The read clamps exactly as `decode_index_maps`;
+    the output stays on the frontier block for the whole KV loop, so each
+    row-head writes that one block back once."""
+    def q_index(bh, ik, pos_ref, len_ref, layer_ref):
+        return (bh, 0, 0)
+
+    def kv_index(bh, ik, pos_ref, len_ref, layer_ref):
+        first, last = _block_bounds(pos_ref[bh // hkv], 1, window, bkv)
+        return (layer_ref[0] * rows + bh, jnp.clip(ik, first, last), 0)
+
+    def out_kv_index(bh, ik, pos_ref, len_ref, layer_ref):
+        return (layer_ref[0] * rows + bh,
+                _frontier_block(pos_ref[bh // hkv], bkv, nk), 0)
+
+    return q_index, kv_index, out_kv_index
 
 
 def paged_decode_index_maps(*, lq: int, hkv: int, bs: int,
@@ -318,12 +384,69 @@ def _launch(kernel, q, kv_arrays, pos, *, bkv, interpret, debug_visits,
     return (out, outs[1]) if debug_visits else out
 
 
+def _update_launch(q, k, v, k_new, v_new, pos, lens, layer, *, bkv,
+                   interpret, window, softcap, scale):
+    """pallas_call assembly for the in-place variant: k/v are the stacked
+    (n_layers, B, Hkv, Lk, D) caches, aliased to the second and third
+    outputs."""
+    b, hq, lq, d = q.shape
+    n, _, hkv, lk, _ = k.shape
+    assert lq == 1, "the in-place decode takes one new token per row"
+    bkv = min(bkv, lk)          # the aliased cache cannot be padded
+    group = hq // hkv
+    rows = b * hkv
+    nk = ceil_div(lk, bkv)
+
+    qr = q.reshape(b, hkv, group, d).reshape(rows, group, d)
+    kr, vr = (a.reshape(n * rows, lk, d) for a in (k, v))
+    knr, vnr = (a.astype(k.dtype).reshape(rows, 1, d)
+                for a in (k_new, v_new))
+
+    q_index, kv_index, out_kv_index = update_decode_index_maps(
+        hkv=hkv, bkv=bkv, nk=nk, rows=rows, window=window)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(rows, nk),
+        in_specs=[pl.BlockSpec((1, group, d), q_index),
+                  pl.BlockSpec((1, bkv, d), kv_index),
+                  pl.BlockSpec((1, bkv, d), kv_index),
+                  pl.BlockSpec((1, 1, d), q_index),
+                  pl.BlockSpec((1, 1, d), q_index)],
+        out_specs=[pl.BlockSpec((1, group, d), q_index),
+                   pl.BlockSpec((1, bkv, d), out_kv_index),
+                   pl.BlockSpec((1, bkv, d), out_kv_index)],
+        scratch_shapes=[
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, d), jnp.float32),
+        ],
+    )
+    out, kr, vr = pl.pallas_call(
+        functools.partial(_update_kernel, scale=scale, window=window,
+                          softcap=softcap, lq=1, hkv=hkv, bkv=bkv, nk=nk,
+                          lk_real=lk),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((rows, group, d), q.dtype),
+                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(vr.shape, vr.dtype)],
+        # operands: pos, lengths, layer, q, k, v, k_new, v_new
+        input_output_aliases={4: 1, 5: 2},
+        interpret=interpret,
+    )(pos, lens, layer, qr, kr, vr, knr, vnr)
+    return (out.reshape(b, hkv, group, 1, d).reshape(b, hq, 1, d),
+            kr.reshape(k.shape), vr.reshape(v.shape))
+
+
 def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         pos, window: Optional[int] = None,
                         softcap: Optional[float] = None,
                         scale: Optional[float] = None, bkv: int = 128,
                         interpret: Optional[bool] = None,
-                        debug_visits: bool = False):
+                        debug_visits: bool = False,
+                        k_new: Optional[jax.Array] = None,
+                        v_new: Optional[jax.Array] = None, layer=0,
+                        lengths=None):
     """q: (B, Hq, Lq, D) short query; k, v: (B, Hkv, Lk, D) cache.
 
     pos: per-row (B,) cache position (or a scalar, broadcast): row b's
@@ -334,12 +457,25 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     debug_visits=True additionally returns an (B*Hkv, nk) int32 map of KV
     blocks whose compute actually ran — the block-pruning evidence used by
     tests and benchmarks (interpret/debug use).
+
+    In place: with k_new/v_new (B, Hkv, 1, D), k and v are the stacked
+    (n_layers, B, Hkv, Lk, D) caches. Row b's new token is rounded to the
+    cache dtype, written at position pos[b] of layer `layer` unless
+    lengths[b] == 0 (None: every row writes), and attended with the rest of
+    the row's context. Returns (out, k, v), k and v updated in place.
     """
     if interpret is None:
         interpret = interpret_mode()
     b = q.shape[0]
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if k_new is not None:
+        assert not debug_visits, "the in-place launch has no visit map"
+        return _update_launch(
+            q, k, v, k_new, v_new, as_row_vector(pos, b),
+            as_row_vector(lengths, b, fill=1),
+            jnp.asarray(layer, jnp.int32).reshape(1), bkv=bkv,
+            interpret=interpret, window=window, softcap=softcap, scale=scale)
     lk_real = k.shape[2]
     k, v = pad_to(k, bkv, 2), pad_to(v, bkv, 2)
     return _launch(_dense_kernel, q, [k, v], as_row_vector(pos, b),

@@ -18,6 +18,12 @@ others. Every impl also accepts `lengths` (per-row valid query counts for a
 right-padded chunk): the varlen prefill kernel PRUNES with it; the others
 ignore it (their outputs at invalid positions are garbage the engine never
 consumes, and masking them would change nothing downstream).
+
+The serving routes ("pallas-prefill", "pallas-decode", "ref") also take
+`k_new`/`v_new` and `layer`: k/v are then the stacked per-slot caches of
+every layer, the step's valid new rows are written into layer `layer`
+before it is attended, and the impl returns (out, k, v). The kernels do
+that write in place; the ref path scatters the rows and reads the layer.
 """
 from __future__ import annotations
 
@@ -51,6 +57,22 @@ def _maybe_dequant(q, k, v, k_scale, v_scale):
     if k_scale is None:
         return k, v
     return _dequant(k, k_scale, q.dtype), _dequant(v, v_scale, q.dtype)
+
+
+def _write_rows(cache: jax.Array, new: jax.Array, layer, pos: jax.Array,
+                lengths: Optional[jax.Array]) -> jax.Array:
+    """The ref path's cache write: row b's first lengths[b] new rows
+    ((B, Hkv, l, D), all l when lengths is None), rounded to the cache
+    dtype, land at pos[b].. of layer `layer` of the stacked (n_layers, B,
+    Hkv, Lk, D) cache; pad rows and rows past Lk are dropped."""
+    b, _, l, _ = new.shape
+    i = jnp.arange(l)
+    idx = pos[:, None] + i                                     # (B, l)
+    if lengths is not None:
+        idx = jnp.where(i < lengths[:, None], idx, cache.shape[3])
+    vals = new.astype(cache.dtype).transpose(0, 2, 1, 3)       # (B, l, H, D)
+    return cache.at[layer, jnp.arange(b)[:, None], :, idx].set(
+        vals, mode="drop")
 
 
 def _gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
@@ -91,8 +113,15 @@ def _attention_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        k_scale: Optional[jax.Array] = None,
                        v_scale: Optional[jax.Array] = None,
                        block_tables: Optional[jax.Array] = None,
-                       policy: ExecutionPolicy) -> jax.Array:
+                       k_new: Optional[jax.Array] = None,
+                       v_new: Optional[jax.Array] = None, layer=None,
+                       policy: ExecutionPolicy):
     assert causal, "the varlen prefill kernel is causal by construction"
+    if k_new is not None:
+        return flash_prefill_pallas(
+            q, k, v, pos=offset, lengths=lengths, window=window,
+            softcap=softcap, scale=scale, bq=policy.bq, bkv=policy.bkv,
+            k_new=k_new, v_new=v_new, layer=layer)
     if block_tables is not None:
         if k_scale is not None:
             return flash_prefill_paged_quant_pallas(
@@ -121,8 +150,15 @@ def _attention_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       k_scale: Optional[jax.Array] = None,
                       v_scale: Optional[jax.Array] = None,
                       block_tables: Optional[jax.Array] = None,
-                      policy: ExecutionPolicy) -> jax.Array:
+                      k_new: Optional[jax.Array] = None,
+                      v_new: Optional[jax.Array] = None, layer=None,
+                      policy: ExecutionPolicy):
     assert causal, "the decode kernel is causal by construction"
+    if k_new is not None:
+        return flash_decode_pallas(
+            q, k, v, pos=offset, window=window, softcap=softcap, scale=scale,
+            bkv=policy.bkv, k_new=k_new, v_new=v_new, layer=layer,
+            lengths=lengths)
     if block_tables is not None:
         if k_scale is not None:
             return flash_decode_paged_quant_pallas(
@@ -148,7 +184,17 @@ def _attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    k_scale: Optional[jax.Array] = None,
                    v_scale: Optional[jax.Array] = None,
                    block_tables: Optional[jax.Array] = None,
-                   policy: ExecutionPolicy) -> jax.Array:
+                   k_new: Optional[jax.Array] = None,
+                   v_new: Optional[jax.Array] = None, layer=None,
+                   policy: ExecutionPolicy):
+    if k_new is not None:
+        k = _write_rows(k, k_new, layer, offset, lengths)
+        v = _write_rows(v, v_new, layer, offset, lengths)
+        out = _attention_ref(q, k[layer].astype(q.dtype),
+                             v[layer].astype(q.dtype), causal=causal,
+                             window=window, softcap=softcap, scale=scale,
+                             offset=offset, policy=policy)
+        return out, k, v
     if block_tables is not None:
         # gather codes AND scales through the table, then dequantize — the
         # same value order as dequantize-then-gather, without a f32 pool copy

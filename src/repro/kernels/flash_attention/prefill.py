@@ -24,7 +24,13 @@ chunk shape:
     to a (group*bq, D) MXU operand — so K/V tiles are read once per kv-head;
   * a fused INT8-KV variant takes `(codes, pow2 scale)` and dequantizes in
     VMEM, rounding through `cast_dtype` (the q dtype) so it is bit-identical
-    to dequantize-then-dense-kernel.
+    to dequantize-then-dense-kernel;
+  * an IN-PLACE variant (`k_new`/`v_new` given) takes the stacked bf16
+    cache of every layer plus the chunk's new rows, reads layer `layer`
+    through its index map, merges the valid new rows into the KV blocks
+    that hold them in VMEM, attends over the merged blocks, and writes
+    those blocks (at most two for a chunk no longer than a block) back
+    through outputs aliased to the cache.
 
 Rows' invalid (right-pad) query positions return ZEROS — deterministic and
 never consumed (the engine gathers each row's last VALID position).
@@ -43,12 +49,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..common import interpret_mode, pad_to
 from .shared import NEG_INF as _NEG_INF
-from .shared import as_row_vector, vmem_dequant
+from .shared import as_row_vector, merge_rows, vmem_dequant, zero_tail
 
 __all__ = ["flash_prefill_pallas", "flash_prefill_quant_pallas",
            "flash_prefill_paged_pallas", "flash_prefill_paged_quant_pallas",
            "prefill_block_visits", "prefill_index_maps",
-           "paged_prefill_index_maps"]
+           "paged_prefill_index_maps", "update_prefill_index_maps"]
 
 
 def _q_last_block(ln, bq: int):
@@ -141,6 +147,68 @@ def _dense_kernel(pos_ref, len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
                   o_ref, visits_ref, m_ref, l_ref, acc_ref, **kw)
 
 
+def _write_blocks(start, ln, iq, ik, *, bq: int, bkv: int, nk: int):
+    """Where an in-place launch writes at grid step (iq, ik) of a row at
+    cache position `start` with `ln` valid new tokens: (the output's KV
+    block, whether the step merges into it). The new rows span blocks
+    first..last (one block, unchanged, when ln == 0), however many. Block j
+    is merged at step (iq, j) of the first q-block iq holding one of its
+    new rows; that q-block's KV range reads block j there (it starts at or
+    below the block of its first row and ends at the block of its last).
+    The output moves forward through first..last, reaching each block at
+    its merge step, so each block is one contiguous run of grid steps and
+    is written back once."""
+    n = jnp.maximum(ln, 1)
+    iqc = jnp.minimum(iq, _q_last_block(ln, bq))
+
+    def blk(r):                                 # block of the chunk's row r
+        return jnp.minimum((start + r) // bkv, nk - 1)
+
+    # the block of the row before q-block iqc (row 0's for q-block 0),
+    # merged already unless iqc == 0, and the block of its last row
+    prev = blk(jnp.maximum(iqc * bq - 1, 0))
+    hi = blk(jnp.minimum((iqc + 1) * bq, n) - 1)
+    lo = jnp.where(iqc == 0, prev, prev + 1)
+    block = jnp.where(iq > iqc, hi, jnp.clip(ik, prev, hi))
+    merge = (iq == iqc) & (ik >= lo) & (ik <= hi)
+    return block, merge
+
+
+def _update_kernel(pos_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, kn_ref,
+                   vn_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref, *,
+                   bq: int, bkv: int, nk: int, lk_real: int, hkv: int, **kw):
+    """In-place prefill: the chunk's valid rows merge into the blocks that
+    hold them, which are written to the aliased cache outputs; the
+    attention reads every block it visits with the chunk merged in."""
+    iq, ik = pl.program_id(1), pl.program_id(2)
+    row = pl.program_id(0) // hkv
+    start, ln = pos_ref[row], len_ref[row]
+    _, merge = _write_blocks(start, ln, iq, ik, bq=bq, bkv=bkv, nk=nk)
+
+    @pl.when(merge)
+    def _write():
+        ko_ref[0] = merge_rows(k_ref[0], kn_ref[0], ik * bkv, start, ln)
+        vo_ref[0] = merge_rows(v_ref[0], vn_ref[0], ik * bkv, start, ln)
+
+    # a visited block is block ik (the compute runs inside [first, last])
+    hit = (ik * bkv < start + ln) & (start < (ik + 1) * bkv)
+
+    def load(ref, new_ref):
+        def get():
+            blk = jax.lax.cond(
+                hit, lambda: merge_rows(ref[0], new_ref[0], ik * bkv, start,
+                                        ln),
+                lambda: ref[0])
+            if lk_real % bkv:
+                blk = zero_tail(blk, ik * bkv, lk_real)
+            return blk.astype(jnp.float32)
+        return get
+
+    _online_block(pos_ref, len_ref, q_ref, load(k_ref, kn_ref),
+                  load(v_ref, vn_ref), o_ref, None, m_ref, l_ref, acc_ref,
+                  bq=bq, bkv=bkv, nk=nk, lk_real=lk_real, hkv=hkv, **kw)
+
+
 def _quant_kernel(pos_ref, len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
                   o_ref, *rest, debug_visits, cast_dtype, **kw):
     visits_ref, (m_ref, l_ref, acc_ref) = \
@@ -173,6 +241,40 @@ def prefill_index_maps(*, bq: int, bkv: int, nk: int, hkv: int,
         return (bh, jnp.clip(ik, first, last), 0)
 
     return q_index, kv_index
+
+
+def update_prefill_index_maps(*, bq: int, bkv: int, nk: int, hkv: int,
+                              rows: int, window: Optional[int]):
+    """The index maps of an in-place varlen-prefill launch: q (and the
+    attention output, unclamped), the stacked cache's K/V read, the chunk's
+    new rows (the whole padded chunk, every grid step), and the aliased
+    cache output. The cache is laid out (n_layers*rows, Lk, D): row-head bh
+    of layer i is row i*rows + bh, the layer riding scalar prefetch after
+    the positions and lengths. The read clamps exactly as
+    `prefill_index_maps`; the output walks `_write_blocks`."""
+    def q_index(bh, iq, ik, pos_ref, len_ref, layer_ref):
+        return (bh, 0, jnp.minimum(iq, _q_last_block(len_ref[bh // hkv], bq)),
+                0)
+
+    def o_index(bh, iq, ik, pos_ref, len_ref, layer_ref):
+        return (bh, 0, iq, 0)
+
+    def new_index(bh, iq, ik, pos_ref, len_ref, layer_ref):
+        return (bh, 0, 0)
+
+    def kv_index(bh, iq, ik, pos_ref, len_ref, layer_ref):
+        i = bh // hkv
+        first, last = _kv_bounds(pos_ref[i], len_ref[i], iq, bq=bq, bkv=bkv,
+                                 nk=nk, window=window)
+        return (layer_ref[0] * rows + bh, jnp.clip(ik, first, last), 0)
+
+    def out_kv_index(bh, iq, ik, pos_ref, len_ref, layer_ref):
+        i = bh // hkv
+        block, _ = _write_blocks(pos_ref[i], len_ref[i], iq, ik, bq=bq,
+                                 bkv=bkv, nk=nk)
+        return (layer_ref[0] * rows + bh, block, 0)
+
+    return q_index, o_index, new_index, kv_index, out_kv_index
 
 
 def paged_prefill_index_maps(*, bq: int, bs: int, nblk: int, hkv: int,
@@ -351,6 +453,60 @@ def _launch(kernel, q, kv_arrays, pos, lens, *, bq, bkv, interpret,
     return (out, outs[1]) if debug_visits else out
 
 
+def _update_launch(q, k, v, k_new, v_new, pos, lens, layer, *, bq, bkv,
+                   interpret, window, softcap, scale, lq_real):
+    """pallas_call assembly for the in-place variant: k/v are the stacked
+    (n_layers, B, Hkv, Lk, D) caches, aliased to the second and third
+    outputs; q and k_new/v_new are padded to the q tile."""
+    b, hq, lq, d = q.shape
+    n, _, hkv, lk, _ = k.shape
+    bkv = min(bkv, lk)          # the aliased cache cannot be padded
+    group = hq // hkv
+    rows = b * hkv
+    nq, nk = lq // bq, -(-lk // bkv)
+
+    qr = q.reshape(b, hkv, group, lq, d).reshape(rows, group, lq, d)
+    kr, vr = (a.reshape(n * rows, lk, d) for a in (k, v))
+    knr, vnr = (pad_to(a.astype(k.dtype), bq, 2).reshape(rows, lq, d)
+                for a in (k_new, v_new))
+
+    q_index, o_index, new_index, kv_index, out_kv_index = \
+        update_prefill_index_maps(bq=bq, bkv=bkv, nk=nk, hkv=hkv, rows=rows,
+                                  window=window)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(rows, nq, nk),
+        in_specs=[pl.BlockSpec((1, group, bq, d), q_index),
+                  pl.BlockSpec((1, bkv, d), kv_index),
+                  pl.BlockSpec((1, bkv, d), kv_index),
+                  pl.BlockSpec((1, lq, d), new_index),
+                  pl.BlockSpec((1, lq, d), new_index)],
+        out_specs=[pl.BlockSpec((1, group, bq, d), o_index),
+                   pl.BlockSpec((1, bkv, d), out_kv_index),
+                   pl.BlockSpec((1, bkv, d), out_kv_index)],
+        scratch_shapes=[
+            pltpu.VMEM((group * bq, 1), jnp.float32),
+            pltpu.VMEM((group * bq, 1), jnp.float32),
+            pltpu.VMEM((group * bq, d), jnp.float32),
+        ],
+    )
+    out, kr, vr = pl.pallas_call(
+        functools.partial(_update_kernel, scale=scale, window=window,
+                          softcap=softcap, bq=bq, group=group, hkv=hkv,
+                          bkv=bkv, nk=nk, lk_real=lk),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((rows, group, lq, d), q.dtype),
+                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(vr.shape, vr.dtype)],
+        # operands: pos, lengths, layer, q, k, v, k_new, v_new
+        input_output_aliases={4: 1, 5: 2},
+        interpret=interpret,
+    )(pos, lens, layer, qr, kr, vr, knr, vnr)
+    out = out.reshape(b, hkv, group, lq, d).reshape(b, hq, lq, d)
+    return out[:, :, :lq_real], kr.reshape(k.shape), vr.reshape(v.shape)
+
+
 def _prep(q, pos, lengths, bq: int, interpret):
     """Resolve interpret/bq, pad Lq to a bq multiple, build (B,) vectors."""
     if interpret is None:
@@ -366,7 +522,9 @@ def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          softcap: Optional[float] = None,
                          scale: Optional[float] = None, bq: int = 32,
                          bkv: int = 128, interpret: Optional[bool] = None,
-                         debug_visits: bool = False):
+                         debug_visits: bool = False,
+                         k_new: Optional[jax.Array] = None,
+                         v_new: Optional[jax.Array] = None, layer=0):
     """q: (B, Hq, Lq, D) right-padded prompt chunk; k, v: (B, Hkv, Lk, D)
     cache (the chunk's keys already written at pos[b]..pos[b]+lengths[b]-1).
 
@@ -379,11 +537,25 @@ def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     debug_visits=True additionally returns a (B*Hkv, nq, nk) int32 map of
     (q-block, KV-block) pairs whose compute actually ran — the pruning
     evidence used by tests and benchmarks (interpret/debug use).
+
+    In place: with k_new/v_new (B, Hkv, Lq, D), the chunk's keys and values
+    are NOT yet in the cache: k and v are the stacked (n_layers, B, Hkv,
+    Lk, D) caches, and row b's first lengths[b] new rows, rounded to the
+    cache dtype, are written at pos[b].. of layer `layer` and attended.
+    Pad rows, and rows of a row with lengths[b] == 0, are never written.
+    Returns (out, k, v), k and v updated in place.
     """
     lq_real = q.shape[2]
     q, pos, lens, bq, interpret = _prep(q, pos, lengths, bq, interpret)
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if k_new is not None:
+        assert not debug_visits, "the in-place launch has no visit map"
+        return _update_launch(
+            q, k, v, k_new, v_new, pos, lens,
+            jnp.asarray(layer, jnp.int32).reshape(1), bq=bq, bkv=bkv,
+            interpret=interpret, window=window, softcap=softcap, scale=scale,
+            lq_real=lq_real)
     lk_real = k.shape[2]
     k, v = pad_to(k, bkv, 2), pad_to(v, bkv, 2)
     return _launch(_dense_kernel, q, [k, v], pos, lens, bq=bq, bkv=bkv,

@@ -20,7 +20,9 @@ __all__ = ["KVCache", "PagedKVCache", "PagedQuantKVCache", "attn_init",
 class KVCache(NamedTuple):
     """Pre-allocated decode cache. k/v: (B, Hkv, L_max, D); pos: (B,) vector —
     every batch row ("slot" in the serving engine) sits at its own position,
-    the substrate for continuous per-slot batching."""
+    the substrate for continuous per-slot batching. The layer loop keeps
+    every layer's k/v stacked, (n_layers, B, Hkv, L_max, D), and the
+    attention op writes each step's rows into its layer in place."""
     k: jax.Array
     v: jax.Array
     pos: jax.Array
@@ -231,9 +233,14 @@ def attn_apply(p, x: jax.Array, *, n_heads: int, n_kv: int, causal: bool = True,
                rope_theta: float = 10000.0, positions: Optional[jax.Array] = None,
                cache: Optional[KVCache] = None,
                lengths: Optional[jax.Array] = None,
+               layer: Optional[jax.Array] = None,
                policy: QuantPolicy = QuantPolicy()):
     """Self attention. Returns (out, new_cache). With a cache, x holds the new
     token(s) and attends to cache[:pos[b]] + x, per batch row.
+
+    A per-slot KVCache comes stacked over the layer loop, (n_layers, B,
+    Hkv, L_max, D), with `layer` the index of this block's layer; the other
+    cache types hold this layer alone.
 
     lengths: optional (B,) count of VALID new tokens per row (continuous
     batching: a right-padded batched prefill, or rows sitting a call out).
@@ -318,13 +325,14 @@ def attn_apply(p, x: jax.Array, *, n_heads: int, n_kv: int, causal: bool = True,
                                k_scale=new_cache.k_scale,
                                v_scale=new_cache.v_scale)
         else:
-            ck = upd(cache.k, k)
-            cv = upd(cache.v, v)
+            assert layer is not None, "a per-slot KVCache comes stacked"
+            # the new rows are written inside the attention op, in place;
+            # the per-row causal mask at offset=start[b] kills each row's
+            # not-yet-written tail
+            out, ck, cv = _cached_attn(q, cache.k, cache.v, start, l, causal,
+                                       window, softcap, lengths=lengths,
+                                       k_new=k, v_new=v, layer=layer)
             new_cache = KVCache(ck, cv, new_pos)
-            # attend over the full (static-length) cache; the per-row causal
-            # mask at offset=start[b] kills each row's not-yet-written tail
-            out = _cached_attn(q, ck, cv, start, l, causal, window, softcap,
-                               lengths=lengths)
         out = _tp(_merge_heads(out), None, "model")
         return _tp(linear(p["o"], out, policy), "model", None), new_cache
 
@@ -340,21 +348,28 @@ def attn_apply(p, x: jax.Array, *, n_heads: int, n_kv: int, causal: bool = True,
 
 
 def _cached_attn(q, ck, cv, start, l, causal, window, softcap,
-                 lengths=None, k_scale=None, v_scale=None, block_tables=None):
+                 lengths=None, k_scale=None, v_scale=None, block_tables=None,
+                 k_new=None, v_new=None, layer=None):
     """Decode-path attention: row b's query positions start[b]..start[b]+l-1
     over a cache of static length; the per-row offset lines the causal mask up
     and also masks the not-yet-written tail (kpos <= qpos < start[b]+l).
     lengths (B,) marks the valid query count of a right-padded chunk — the
     varlen prefill kernel prunes with it. With k_scale/v_scale, ck/cv are
-    int8 codes (dequant happens at dispatch or inside the kernels)."""
-    if k_scale is None:
+    int8 codes (dequant happens at dispatch or inside the kernels). With
+    k_new/v_new, ck/cv are the stacked per-slot caches, written as well as
+    read by the op, which then returns (out, ck, cv)."""
+    if k_new is not None:
+        with jax.named_scope("kv_cache"):       # the new rows' cache format
+            k_new, v_new = k_new.astype(ck.dtype), v_new.astype(cv.dtype)
+    elif k_scale is None:
         with jax.named_scope("kv_cache"):
             ck, cv = ck.astype(q.dtype), cv.astype(q.dtype)
     with jax.named_scope("attention"):
         return aio_ops.attention(q, ck, cv, causal=True, window=window,
                                  softcap=softcap, offset=start,
                                  lengths=lengths, k_scale=k_scale,
-                                 v_scale=v_scale, block_tables=block_tables)
+                                 v_scale=v_scale, block_tables=block_tables,
+                                 k_new=k_new, v_new=v_new, layer=layer)
 
 
 def cross_attn_apply(p, x: jax.Array, memory: jax.Array, *, n_heads: int,

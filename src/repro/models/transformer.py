@@ -190,11 +190,13 @@ def _select_rows(new_cache, old_cache, active: jax.Array):
 def _block_apply(kind: str, p, x: jax.Array, cfg: ModelConfig, *,
                  cache=None, memory: Optional[jax.Array] = None,
                  positions: Optional[jax.Array] = None,
-                 lengths: Optional[jax.Array] = None):
+                 lengths: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None):
     """Returns (x, new_cache, aux_loss).
 
     lengths: (B,) valid-new-token counts for cached paths (see attn_apply);
-    recurrent blocks freeze state rows where lengths == 0."""
+    recurrent blocks freeze state rows where lengths == 0. layer: this
+    block's index in a stacked per-slot KVCache (see attn_apply)."""
     aux = jnp.zeros((), jnp.float32)
     pol = cfg.quant
     if kind in ("dense", "dense_local", "dense_global", "moe", "enc",
@@ -229,7 +231,7 @@ def _block_apply(kind: str, p, x: jax.Array, cfg: ModelConfig, *,
             p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
             causal=causal, window=window, softcap=cfg.softcap_attn,
             rope_theta=cfg.rope_theta, positions=positions, cache=cache,
-            lengths=lengths, policy=pol)
+            lengths=lengths, layer=layer, policy=pol)
         if cfg.post_norm:
             h = apply_norm(cfg.norm, p["pn1"], h)
         x = x + h
@@ -279,7 +281,7 @@ def _block_apply(kind: str, p, x: jax.Array, cfg: ModelConfig, *,
         h, new_cache = attn_apply(
             p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
             causal=True, rope_theta=cfg.rope_theta, positions=positions,
-            cache=cache, lengths=lengths, policy=pol)
+            cache=cache, lengths=lengths, layer=layer, policy=pol)
         x = x + h
         h = apply_norm(cfg.norm, p["lnx"], x)
         h = cross_attn_apply(p["xattn"], h, memory, n_heads=cfg.n_heads,
@@ -621,36 +623,50 @@ def resident_format(params) -> Optional[str]:
 def _run_segment(seg_params, unit: Tuple[str, ...], n: int, x: jax.Array,
                  cfg: ModelConfig, memory=None, positions=None,
                  seg_caches=None, lengths=None):
-    """Scan the unit n times; returns (x, new_caches, aux)."""
+    """Scan the unit n times; returns (x, new_caches, aux).
+
+    A per-slot KVCache rides the scan's carry as its stacked k/v arrays,
+    which the attention op updates in place, layer by layer; its positions
+    and every other cache (recurrent states, paged pools, int8 caches) go
+    through the scan's xs/ys, one layer's slice per step."""
     scanned = {k: v for k, v in seg_params.items()
                if not k.endswith("shared_attn")}
     shared = {k: v for k, v in seg_params.items()
               if k.endswith("shared_attn")}
-    caches = seg_caches or {}
+    caches = {k: v for k, v in (seg_caches or {}).items() if v is not None}
+    carried = {k: (c.k, c.v) for k, c in caches.items()
+               if isinstance(c, KVCache)}
+    xs_caches = {k: c.pos if k in carried else c for k, c in caches.items()}
 
     def body(carry, xs):
-        h, aux = carry
-        layer_params, layer_caches = xs
-        new_caches = {}
+        h, aux, kv = carry
+        layer_params, layer_caches, layer = xs
+        kv, new_caches = dict(kv), {}
         for j, kind in enumerate(unit):
             key = f"{j}_{kind}"
             p = shared[key] if key in shared else layer_params[key]
-            c = layer_caches.get(key) if layer_caches else None
+            c = layer_caches.get(key)
+            if key in carried:
+                c = KVCache(*kv[key], c)
             h, nc, a = _block_apply(kind, p, h, cfg, cache=c, memory=memory,
-                                    positions=positions, lengths=lengths)
+                                    positions=positions, lengths=lengths,
+                                    layer=layer)
             aux = aux + a
+            if key in carried:
+                kv[key], nc = (nc.k, nc.v), nc.pos
             if nc is not None:
                 new_caches[key] = nc
-        return (h, aux), new_caches
+        return (h, aux, kv), new_caches
 
     if cfg.remat and seg_caches is None:
         body = jax.checkpoint(body)
 
-    xs_caches = {k: v for k, v in caches.items() if v is not None}
     unroll = min(cfg.scan_unroll, n) if cfg.scan_unroll > 1 else 1
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)),
-        ({k: v for k, v in scanned.items()}, xs_caches), unroll=unroll)
+    (x, aux, kv), new_caches = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), carried),
+        (scanned, xs_caches, jnp.arange(n)), unroll=unroll)
+    for key, (k, v) in kv.items():
+        new_caches[key] = KVCache(k, v, new_caches[key])
     return x, new_caches, aux
 
 

@@ -114,6 +114,50 @@ def test_decode_int8_fused_bit_exact_vs_dequant():
                                     offset=pos, **kw))
 
 
+# ====================================================== in-place cache update
+def written(cache, new, layer, pos, lens):
+    """The stacked cache with row b's first lens[b] new rows, rounded to the
+    cache dtype, at pos[b].. of `layer` — what an in-place launch returns."""
+    out = np.asarray(cache).copy()
+    new = np.asarray(jnp.asarray(new).astype(cache.dtype))
+    for b, (p, n) in enumerate(zip(np.asarray(pos), np.asarray(lens))):
+        out[layer, b, :, p:p + n] = new[b, :, :n]
+    return out
+
+
+@pytest.mark.parametrize("lk,pos,lens,window,softcap", [
+    # an empty row, a row sitting the step out, a block's first slot, the
+    # cache's last slot
+    (MAX_LEN, [0, 5, 128, MAX_LEN - 1], [1, 0, 1, 1], None, None),
+    (MAX_LEN, [0, 5, 128, MAX_LEN - 1], [1, 1, 0, 1], 40, 30.0),
+    # an unaligned cache: the last block is partial
+    (200, [199, 64, 127, 0], [1, 1, 1, 0], None, None),
+])
+def test_decode_in_place_update(lk, pos, lens, window, softcap):
+    """The in-place launch returns the stacked cache with each row's new
+    token at pos[b] of its layer — other layers, rows sitting the step out
+    and every other position untouched — and attention outputs bit-equal
+    to writing the cache first, casting it to f32 and running the kernel."""
+    n, layer = 3, 1
+    q, _, _ = qkv(4, 8, 2, 1, lk, 64)
+    k = randn(n, 4, 2, lk, 64, scale=0.5).astype(jnp.bfloat16)
+    v = randn(n, 4, 2, lk, 64).astype(jnp.bfloat16)
+    k_new, v_new = randn(4, 2, 1, 64, scale=0.5), randn(4, 2, 1, 64)
+    pos, lens = jnp.asarray(pos, jnp.int32), jnp.asarray(lens, jnp.int32)
+    out, k2, v2 = flash_decode_pallas(
+        q, k, v, pos=pos, lengths=lens, window=window, softcap=softcap,
+        k_new=k_new, v_new=v_new, layer=layer, interpret=True)
+    want_k = written(k, k_new, layer, pos, lens)
+    want_v = written(v, v_new, layer, pos, lens)
+    np.testing.assert_array_equal(np.asarray(k2), want_k)
+    np.testing.assert_array_equal(np.asarray(v2), want_v)
+    dense = flash_decode_pallas(
+        q, jnp.asarray(want_k[layer]).astype(jnp.float32),
+        jnp.asarray(want_v[layer]).astype(jnp.float32), pos=pos,
+        window=window, softcap=softcap, interpret=True)
+    assert jnp.array_equal(out, dense)
+
+
 # ============================================================ block pruning
 def test_decode_block_pruning_visits():
     """The kernel must VISIT only the KV blocks inside each row's causal

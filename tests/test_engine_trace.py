@@ -1,6 +1,7 @@
 """The serving engine's own trace records: host spans in the profiler's
 trace, named scopes in the step program's compiled HLO, and the admission
 counters of `EngineStats`."""
+import dataclasses
 import re
 import types
 
@@ -118,12 +119,20 @@ def _op_names(hlo: str) -> set:
     return set(re.findall(r'op_name="([^"]*)"', hlo))
 
 
+def _scopes(cfg, params) -> set:
+    eng = ServingEngine(cfg, params, slots=2, max_len=32, prefill_chunk=4)
+    return {p for n in _op_names(eng.step_hlo(4)) for p in n.split("/")}
+
+
 def test_the_step_program_carries_its_scopes(model):
     cfg, params = model
-    eng = ServingEngine(cfg, params, slots=2, max_len=32, prefill_chunk=4)
-    parts = {p for n in _op_names(eng.step_hlo(4)) for p in n.split("/")}
-    for scope in ("kv_cache", "attention", "linear", "unembed", "health"):
+    parts = _scopes(cfg, params)
+    for scope in ("attention", "linear", "unembed", "health"):
         assert scope in parts, scope
+    # the per-slot cache is written inside the attention op; an int8
+    # cache is still quantized and written by XLA, under `kv_cache`
+    qcfg = dataclasses.replace(cfg, kv_quant=True)
+    assert "kv_cache" in _scopes(qcfg, init_params(jax.random.key(3), qcfg))
 
 
 def test_a_weight_conversion_in_linear_is_scoped():

@@ -18,6 +18,8 @@ from repro.configs import get_smoke
 from repro.kernels.flash_attention import (flash_prefill_pallas,
                                            flash_prefill_quant_pallas,
                                            mha_ref, prefill_block_visits)
+from repro.kernels.flash_attention.prefill import (_write_blocks,
+                                                   update_prefill_index_maps)
 from repro.models import init_params
 from repro.models.attention import _dq8, _q8
 from repro.serving import Request, ServingEngine
@@ -125,6 +127,96 @@ def test_prefill_int8_fused_bit_exact_vs_dequant():
         assert_valid_close(fused, mha_ref(q, _dq8(kc, ks, q.dtype),
                                           _dq8(vc, vs, q.dtype), causal=True,
                                           offset=pos, **kw), lens)
+
+
+# ====================================================== in-place cache update
+def written(cache, new, layer, pos, lens):
+    """The stacked cache with row b's first lens[b] new rows, rounded to the
+    cache dtype, at pos[b].. of `layer` — what an in-place launch returns."""
+    out = np.asarray(cache).copy()
+    new = np.asarray(jnp.asarray(new).astype(cache.dtype))
+    for b, (p, n) in enumerate(zip(np.asarray(pos), np.asarray(lens))):
+        out[layer, b, :, p:p + n] = new[b, :, :n]
+    return out
+
+
+@pytest.mark.parametrize("lk,lq,pos,lens,bq,bkv,window,softcap", [
+    # a full chunk crossing a block boundary, a short chunk (pad rows), a
+    # row sitting the step out, a chunk filling the cache's last block
+    (MAX_LEN, 32, [0, 120, 60, MAX_LEN - 32], [32, 9, 0, 32], 32, 128, None,
+     None),
+    # several q-blocks per chunk; window and softcap
+    (MAX_LEN, 32, [100, 0, 200, 126], [32, 32, 3, 0], 8, 128, 40, 30.0),
+    # an unaligned cache: final partial chunks whose pad rows run past its
+    # end, one crossing into the partial last block
+    (300, LQ, [290, 250, 0, 120], [10, LQ, 0, 17], 32, 128, None, None),
+    # a chunk longer than a KV block: up to five blocks written, one of
+    # them the unaligned cache's partial last block
+    (300, 64, [5, 240, 100, 17], [64, 60, 0, 33], 16, 16, 40, None),
+])
+def test_prefill_in_place_update(lk, lq, pos, lens, bq, bkv, window,
+                                 softcap):
+    """The in-place launch returns the stacked cache with each row's valid
+    chunk rows at pos[b].. of its layer — other layers, pad rows, rows
+    sitting the step out and everything past the cache's end untouched —
+    and attention outputs bit-equal to writing the cache first, casting it
+    to f32 and running the kernel."""
+    n, layer = 2, 1
+    q, _, _ = qkv(4, 8, 2, lq, lk, 64)
+    k = randn(n, 4, 2, lk, 64, scale=0.5).astype(jnp.bfloat16)
+    v = randn(n, 4, 2, lk, 64).astype(jnp.bfloat16)
+    k_new, v_new = randn(4, 2, lq, 64, scale=0.5), randn(4, 2, lq, 64)
+    pos, lens = jnp.asarray(pos, jnp.int32), jnp.asarray(lens, jnp.int32)
+    out, k2, v2 = flash_prefill_pallas(
+        q, k, v, pos=pos, lengths=lens, window=window, softcap=softcap,
+        bq=bq, bkv=bkv, k_new=k_new, v_new=v_new, layer=layer,
+        interpret=True)
+    want_k = written(k, k_new, layer, pos, lens)
+    want_v = written(v, v_new, layer, pos, lens)
+    np.testing.assert_array_equal(np.asarray(k2), want_k)
+    np.testing.assert_array_equal(np.asarray(v2), want_v)
+    dense = flash_prefill_pallas(
+        q, jnp.asarray(want_k[layer]).astype(jnp.float32),
+        jnp.asarray(want_v[layer]).astype(jnp.float32), pos=pos,
+        lengths=lens, window=window, softcap=softcap, bq=bq, bkv=bkv,
+        interpret=True)
+    assert jnp.array_equal(out, dense)
+
+
+@pytest.mark.parametrize("lk,bq,bkv,window", [
+    (384, 32, 128, None), (300, 8, 16, 40), (256, 32, 16, None)])
+def test_prefill_in_place_write_schedule(lk, bq, bkv, window):
+    """On the chip an output block starts unread in VMEM and is written
+    back when the output index moves on; interpret mode reloads it every
+    step and cannot see a fault there. So replay the in-place launch's
+    output index over the grid: each block the chunk's rows fall in is
+    reached in one run of steps, in ascending order, and merged exactly
+    once inside its run, at a step whose K/V read is that very block."""
+    nk = -(-lk // bkv)
+    *_, kv_index, out_index = update_prefill_index_maps(
+        bq=bq, bkv=bkv, nk=nk, hkv=1, rows=1, window=window)
+    layer = np.zeros(1, np.int32)
+    for lq in (bq, 4 * bq):
+        for start in (0, bkv - 3, lk - lq, lk - 1):
+            for ln in (0, 1, lq // 2 + 1, lq):
+                pos, lens = np.array([start]), np.array([ln])
+                seq, merged = [], []
+                for iq in range(lq // bq):
+                    for ik in range(nk):
+                        blk = int(out_index(0, iq, ik, pos, lens, layer)[1])
+                        seq.append(blk)
+                        _, merge = _write_blocks(start, ln, iq, ik, bq=bq,
+                                                 bkv=bkv, nk=nk)
+                        if merge:
+                            merged.append(blk)
+                            assert blk == ik
+                            assert int(kv_index(0, iq, ik, pos, lens,
+                                                layer)[1]) == ik
+                runs = [b for i, b in enumerate(seq)
+                        if not i or b != seq[i - 1]]
+                last = min((start + max(ln, 1) - 1) // bkv, nk - 1)
+                want = list(range(min(start // bkv, nk - 1), last + 1))
+                assert runs == want and merged == want, (lq, start, ln)
 
 
 # ============================================================ block pruning

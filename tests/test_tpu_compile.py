@@ -12,13 +12,18 @@ time may hold the TPU compiler's library), and the persistent compilation
 cache is off for these compiles: a cached entry for a described chip cannot
 be read back without one.
 """
+import dataclasses
 import os
+import re
+import types
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import api
+from repro.configs import get_config
 from repro.core import formats as F
 from repro.kernels.aio_matmul.ops import aio_matmul_resident
 from repro.kernels.common import interpret_override
@@ -27,6 +32,8 @@ from repro.kernels.flash_attention.decode import (
 from repro.kernels.flash_attention.prefill import (
     flash_prefill_paged_pallas, flash_prefill_pallas,
     flash_prefill_quant_pallas)
+from repro.models import transformer as T
+from repro.serving.engine import ServingEngine
 
 B, HQ, HKV, D = 8, 12, 2, 128         # qwen2-1.5b attention, 8 serving slots
 L, BQ, BKV, BS = 4096, 32, 128, 16    # cache length, q/KV tiles, paged block
@@ -153,3 +160,90 @@ def test_matmul_codes_compiles(compile_tpu, fmt):
                       ((kpacked, D_FF), jnp.int8),
                       ((B * BQ, 1), jnp.float32), ((1, D_FF), jnp.float32))
     _assert_kernel(hlo)
+
+
+# ------------------------------------------- the engine's step program
+_SLOTS, _CACHE, _LAYERS = 32, 3072, 2         # the chat cell's cache, cut
+_INSTR = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(")
+_SKIP = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant",
+         "while", "custom-call")
+
+
+def _computations(hlo: str) -> dict:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _ops(hlo: str):
+    """(computation, name, opcode, dtype, element count) of every
+    instruction XLA schedules — entry, loop bodies and conditions, not the
+    insides of fusions or of the Pallas custom call."""
+    comps = _computations(hlo)
+    inner = {m.group(1) for lines in comps.values() for line in lines
+             if " fusion(" in line or "custom-call(" in line
+             for m in re.finditer(r"calls=%?([\w.\-]+)", line)}
+    for comp, lines in comps.items():
+        if comp in inner:
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if m:
+                name, dtype, shape, op = m.groups()
+                n = 1
+                for dim in filter(None, shape.split(",")):
+                    n *= int(dim)
+                yield comp, name, op, dtype, n
+
+
+@pytest.mark.parametrize("width", [1, 32])
+def test_engine_step_updates_kv_cache_in_place(one_chip, no_persistent_cache,
+                                               width):
+    """qwen2-1.5b's engine step program (2 layers, 32 slots, a 3072-token
+    cache) makes no pass over the KV cache outside the attention kernel:
+    the kernel writes each step's rows into the stacked cache in place.
+
+    Cache-sized means an element count that is a whole multiple of one
+    layer's K (or V) cache: a layer's slice, the stacked cache, or an f32
+    copy of either. The f32 weights' bf16 copies XLA makes before the layer
+    loop, and the logits, are other sizes; the copies are the only other
+    temporaries allowed beside one layer's cache."""
+    cfg = dataclasses.replace(get_config("qwen2_1p5b"), n_layers=_LAYERS)
+    layer = _SLOTS * cfg.n_kv_heads * _CACHE * cfg.hd      # elements
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = spec(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    caches = spec(jax.eval_shape(lambda: T.init_caches(cfg, _SLOTS, _CACHE)))
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((_SLOTS, width), (_SLOTS,))]
+    engine = types.SimpleNamespace(cfg=cfg)
+
+    def step(p, c, tok, lens):
+        return ServingEngine._step_program(engine, p, c, tok, lens, None)
+
+    with api.policy(backend="pallas", interpret=False):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            params, caches, *ints).compile()
+    hlo = compiled.as_text()
+
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    ops = [o for o in _ops(hlo) if o[2] not in _SKIP]
+    passes = [o[:4] for o in ops if o[4] >= layer and o[4] % layer == 0]
+    assert not passes, f"cache-sized XLA ops: {passes}"
+    weight_copies = sum(2 * o[4] for o in ops
+                        if o[2] == "convert" and o[3] == "bf16")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * layer + weight_copies, (temp, weight_copies)  # bf16
